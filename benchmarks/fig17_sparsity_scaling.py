@@ -54,7 +54,7 @@ def run() -> None:
                 block_m=bm, block_k=bk, block_n=plan.block_n)
             out, us = timed(
                 lambda: jax.block_until_ready(kops.sparce_gemm(
-                    x, w, run_plan, lhs_bitmap=bmp, interpret=True)),
+                    x, w, run_plan, lhs_bitmap=bmp)),
                 warmup=1, iters=2)
             sv = cm.tpu_gemm_time(M, K, N, tile_skip_frac=tile_skip,
                                   dtype_bytes=4)

@@ -16,6 +16,7 @@ from benchmarks.common import emit, timed
 from repro.core import cost_model as cm
 from repro.core import sasa, sprf
 from repro.kernels import sparce_gemm as sgk
+from repro.kernels.ops import interpret_mode
 
 M, K, N = 256, 3456, 384
 
@@ -32,7 +33,7 @@ def run() -> None:
     _, us_a = timed(
         lambda: jax.block_until_ready(sgk.sparce_gemm_gated(
             feats, dense_w, fb.bits, block_m=bm, block_k=bk, block_n=bn,
-            interpret=True)), warmup=1, iters=2)
+            interpret=interpret_mode())), warmup=1, iters=2)
     skip_a = float(fb.sparsity())
     sv_a = cm.tpu_gemm_time(M, K, N, tile_skip_frac=skip_a, dtype_bytes=4)
 
@@ -41,7 +42,7 @@ def run() -> None:
     _, us_b = timed(
         lambda: jax.block_until_ready(sgk.sparce_gemm_gated(
             feats, dense_w, wb.bits, gate="rhs",
-            block_m=bm, block_k=bk, block_n=bn, interpret=True)),
+            block_m=bm, block_k=bk, block_n=bn, interpret=interpret_mode())),
         warmup=1, iters=2)
     skip_b = float(wb.sparsity())
     sv_b = cm.tpu_gemm_time(M, K, N, tile_skip_frac=skip_b, dtype_bytes=4)
@@ -62,7 +63,7 @@ def run() -> None:
     y, us_both = timed(
         lambda: jax.block_until_ready(sgk.sparce_gemm_gated_both(
             feats, pruned, fb.bits, pb.bits,
-            block_m=bm, block_k=bk, block_n=bn, interpret=True)),
+            block_m=bm, block_k=bk, block_n=bn, interpret=interpret_mode())),
         warmup=1, iters=2)
     or_skip = float(jnp.mean(jnp.maximum(
         fb.bits[:, :, None], pb.bits[None, :, :]).astype(jnp.float32)))

@@ -47,7 +47,7 @@ def _case(sparsity: float) -> dict:
 
     def run_fused():
         y, bmp = kops.sparce_mlp_fused(
-            x, w_in, w_out, block_m=BM, block_f=BF, interpret=True)
+            x, w_in, w_out, block_m=BM, block_f=BF)
         return jax.block_until_ready(y), bmp
 
     plan = sasa.MlpPlan(
@@ -56,7 +56,7 @@ def _case(sparsity: float) -> dict:
     def run_two_kernel():
         # Same single implementation the fused-mode fallback serves.
         y, bits = sparse_ops.two_kernel_mlp(
-            x, w_in, w_out, plan, interpret=True)
+            x, w_in, w_out, plan)
         return jax.block_until_ready(y), bits
 
     def run_dense():
@@ -115,7 +115,7 @@ def _glu_case(sparsity: float, act: str = "silu") -> dict:
     def run_fused():
         y, bmp = kops.sparce_glu_mlp_fused(
             x, w_gate, w_in, w_out, block_m=GLU_BM, block_f=BF, act=act,
-            tau=tau, interpret=True)
+            tau=tau)
         return jax.block_until_ready(y), bmp
 
     plan = sasa.MlpPlan(
@@ -124,7 +124,7 @@ def _glu_case(sparsity: float, act: str = "silu") -> dict:
     def run_unfused():
         # Same single implementation the fused-mode fallback serves.
         y, bits = sparse_ops.unfused_glu_mlp(
-            x, w_gate, w_in, w_out, plan, act, tau, interpret=True)
+            x, w_gate, w_in, w_out, plan, act, tau)
         return jax.block_until_ready(y), bits
 
     def run_dense():

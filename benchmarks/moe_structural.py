@@ -17,6 +17,7 @@ from repro.configs import get_config
 from repro.core import cost_model as cm
 from repro.core import sprf
 from repro.kernels import sparce_gemm as sgk
+from repro.kernels.ops import interpret_mode
 from repro.models import moe as moe_lib
 from repro.models import model as model_lib
 
@@ -53,7 +54,7 @@ def run() -> None:
         _, us_k = timed(
             lambda: jax.block_until_ready(sgk.sparce_gemm_gated(
                 buf, wexp, bmp.bits, block_m=8, block_k=128, block_n=128,
-                interpret=True)), warmup=1, iters=2)
+                interpret=interpret_mode())), warmup=1, iters=2)
         skip = float(bmp.sparsity())
         sv = cm.tpu_gemm_time(C, d, ff, tile_skip_frac=skip, dtype_bytes=2)
         emit(f"moe/{arch}/gated_expert_gemm", us_k,

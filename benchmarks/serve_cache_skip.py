@@ -565,7 +565,7 @@ def _run_decode_attn_kernel_sweep() -> list:
         tbl, ln = jnp.asarray(tables), jnp.asarray(lengths)
         out, us = timed(
             lambda: jax.block_until_ready(kops.paged_decode_attn(
-                q, kp, vp, tbl, ln, interpret=True)),
+                q, kp, vp, tbl, ln)),
             warmup=1, iters=2)
         want = paged_gqa_decode_attn_ref(q, kp, vp, tbl, ln)
         err = float(jnp.max(jnp.abs(out - want)))

@@ -32,7 +32,7 @@ print(f"plan: gate={plan.gate} variant={plan.variant} "
 bitmap = sprf.compute_bitmap(x, plan.block_lhs)
 print(f"tile-level sparsity: {float(bitmap.sparsity()):.1%}")
 
-y_sparce = kops.sparce_gemm(x, w, plan, lhs_bitmap=bitmap, interpret=True)
+y_sparce = kops.sparce_gemm(x, w, plan, lhs_bitmap=bitmap)
 y_dense = jnp.dot(x, w)
 err = float(jnp.max(jnp.abs(y_sparce - y_dense)))
 print(f"max |sparce - dense| = {err:.2e}  (bit-exact transform)")

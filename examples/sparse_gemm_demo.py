@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.core import cost_model, sprf
 from repro.kernels import sparce_gemm as sgk
+from repro.kernels.ops import interpret_mode
 
 s = float(sys.argv[1]) if len(sys.argv) > 1 else 0.7
 M, K, N = 256, 3456, 384  # paper Fig. 17 inner dims (padded M)
@@ -28,9 +29,11 @@ print(f"word sparsity {s:.0%} -> {skipped}/{total_tiles} tiles skippable "
       f"({skipped / total_tiles:.1%})")
 
 y_g = sgk.sparce_gemm_gated(
-    x, w, bmp.bits, block_m=bm, block_k=bk, block_n=128, interpret=True)
+    x, w, bmp.bits, block_m=bm, block_k=bk, block_n=128,
+    interpret=interpret_mode())
 y_c = sgk.sparce_gemm_compacted(
-    x, w, bmp.bits, block_m=bm, block_k=bk, block_n=128, interpret=True)
+    x, w, bmp.bits, block_m=bm, block_k=bk, block_n=128,
+    interpret=interpret_mode())
 y_d = jnp.dot(x, w)
 print(f"gated     max err vs dense: {float(jnp.abs(y_g - y_d).max()):.2e}")
 print(f"compacted max err vs dense: {float(jnp.abs(y_c - y_d).max()):.2e}")

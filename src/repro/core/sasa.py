@@ -561,7 +561,7 @@ def plan_glu_mlp_cached(
 def autotune_mlp_plan(
     m: int, k: int, f: int, n: int, *,
     measured_block_sparsity: float, dtype: str = "float32",
-    sample_inputs=None, iters: int = 2, interpret: bool = True,
+    sample_inputs=None, iters: int = 2,
 ) -> Tuple[MlpPlan, dict]:
     """Measuring autotuner: time the fused vs two-kernel candidates.
 
@@ -578,7 +578,7 @@ def autotune_mlp_plan(
     import jax.numpy as jnp
 
     key = ("mlp-tuned", m, k, f, n, dtype, _bucket_sparsity(
-        measured_block_sparsity), interpret)
+        measured_block_sparsity))
     hit = _PLAN_CACHE.get(key)
     if hit is not None:
         _PLAN_CACHE_STATS["hits"] += 1
@@ -606,14 +606,12 @@ def autotune_mlp_plan(
 
     def run_fused():
         y, _ = kops.sparce_mlp_fused(
-            x, w_in, w_out, block_m=plan.block_m, block_f=plan.block_f,
-            interpret=interpret)
+            x, w_in, w_out, block_m=plan.block_m, block_f=plan.block_f)
         return jax.block_until_ready(y)
 
     def run_two_kernel():
         # Same pipeline the fused-mode fallback serves (single impl).
-        y, _ = sparse_ops.two_kernel_mlp(
-            x, w_in, w_out, plan, interpret=interpret)
+        y, _ = sparse_ops.two_kernel_mlp(x, w_in, w_out, plan)
         return jax.block_until_ready(y)
 
     timings = {}

@@ -8,8 +8,8 @@ BP and WG GEMMs on error sparsity -- the paper's training-time story
 exceed FP gains because errors are sparser than features).
 
 Modes:
-  * 'kernel'    -- Pallas kernels (interpret=True on this CPU container;
-                   the deployment flag flips to compiled TPU kernels).
+  * 'kernel'    -- Pallas kernels (compiled on a TPU, interpreted on the
+                   CPU backend; ``kernels.ops.interpret_mode`` decides).
   * 'reference' -- masked-dense jnp ops with identical semantics. This is
                    what the distributed model stacks use so that pjit/XLA
                    sees plain einsums (and the dry-run lowers collectives
@@ -44,7 +44,6 @@ class SparsityConfig:
     gate_weights: bool = False  # static pruned-weight sparsity
     weight_sparsity: float = 0.0  # pruning level applied at init when >0
     relufication: bool = False  # swap smooth MLP act for relu^2
-    interpret: bool = True  # Pallas interpret mode (CPU container)
     # Planner-v2 inputs (mode='fused'): the measured block-sparsity
     # estimate the MLP plan is built from (bucketed; a changed value
     # means a retrace, so the serving engine only updates it when the
@@ -78,8 +77,7 @@ class SparsityConfig:
 
 
 def _run_matmul(
-    x, w, lbits, rbits, plan: sasa.SkipPlan, mode: str, interpret: bool,
-    out_dtype,
+    x, w, lbits, rbits, plan: sasa.SkipPlan, mode: str, out_dtype,
 ):
     if mode == "off" or plan.gate == "none":
         return jnp.dot(
@@ -91,7 +89,7 @@ def _run_matmul(
         rb = sprf.TileBitmap(rbits, plan.block_rhs, w.shape) if rbits is not None else None
         return kops.sparce_gemm(
             x, w, plan, lhs_bitmap=lb, rhs_bitmap=rb,
-            out_dtype=out_dtype, interpret=interpret,
+            out_dtype=out_dtype,
         )
     # reference: masked dense (bit-exact with the kernel contract)
     return kref.sparce_gemm_ref(
@@ -103,17 +101,17 @@ def _run_matmul(
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _sparce_matmul(x, w, lbits, rbits, plan, mode, interpret):
-    return _run_matmul(x, w, lbits, rbits, plan, mode, interpret, x.dtype)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _sparce_matmul(x, w, lbits, rbits, plan, mode):
+    return _run_matmul(x, w, lbits, rbits, plan, mode, x.dtype)
 
 
-def _fwd(x, w, lbits, rbits, plan, mode, interpret):
-    y = _run_matmul(x, w, lbits, rbits, plan, mode, interpret, x.dtype)
+def _fwd(x, w, lbits, rbits, plan, mode):
+    y = _run_matmul(x, w, lbits, rbits, plan, mode, x.dtype)
     return y, (x, w, lbits, rbits)
 
 
-def _bwd(plan, mode, interpret, res, g):
+def _bwd(plan, mode, res, g):
     x, w, lbits, rbits = res
     m, k = x.shape
     _, n = w.shape
@@ -129,7 +127,7 @@ def _bwd(plan, mode, interpret, res, g):
         block_m=plan.block_m, block_k=plan.block_n, block_n=plan.block_k,
     )
     dx = _run_matmul(
-        g, w.T, gbits, None, dx_plan, mode, interpret, x.dtype
+        g, w.T, gbits, None, dx_plan, mode, x.dtype
     )
     # --- WG: dw = x^T @ g, gated on the FEATURE bitmap (transposed). ---
     wg_gate = "none"
@@ -142,7 +140,7 @@ def _bwd(plan, mode, interpret, res, g):
         block_m=plan.block_k, block_k=plan.block_m, block_n=plan.block_n,
     )
     dw = _run_matmul(
-        x.T, g, xtbits, None, dw_plan, mode, interpret, w.dtype
+        x.T, g, xtbits, None, dw_plan, mode, w.dtype
     )
     return dx, dw, None, None
 
@@ -184,7 +182,7 @@ def sparce_matmul(
             )
     lbits = lhs_bitmap.bits if lhs_bitmap is not None else None
     rbits = rhs_bitmap.bits if rhs_bitmap is not None else None
-    return _sparce_matmul(x, w, lbits, rbits, plan, cfg.mode, cfg.interpret)
+    return _sparce_matmul(x, w, lbits, rbits, plan, cfg.mode)
 
 
 # ------------------------------------------------------------- fused MLP
@@ -194,17 +192,15 @@ def sparce_matmul(
 # tiles' w_out stripe fetches never issued. Backward runs the reference
 # semantics (recompute-from-x), so the op stays trainable.
 
-def _fused_mlp_run(x, w_in, w_out, plan, act, interpret):
-    from repro.kernels import ops as kops
-
+def _fused_mlp_run(x, w_in, w_out, plan, act):
     y, bmp = kops.sparce_mlp_fused(
         x, w_in, w_out, block_m=plan.block_m, block_f=plan.block_f,
-        act=act, interpret=interpret,
+        act=act,
     )
     return y, bmp.bits
 
 
-def two_kernel_mlp(x, w_in, w_out, plan, act="relu", interpret=True):
+def two_kernel_mlp(x, w_in, w_out, plan, act="relu"):
     """The pre-fused pipeline the planner falls back to: dense up-proj,
     producer-fused relu+bitmap kernel, bitmap-gated down-proj kernel.
     Three HBM round trips of the intermediate -- what the fused variant
@@ -212,31 +208,24 @@ def two_kernel_mlp(x, w_in, w_out, plan, act="relu", interpret=True):
     single implementation is shared by the fused-mode fallback, the
     measuring autotuner, and the benchmarks so all three time/serve the
     same pipeline. Returns (y, bits)."""
-    from repro.kernels import ops as kops
-
     h = jnp.dot(x, w_in)
-    a, bmp = kops.relu_with_bitmap(
-        h, (plan.block_m, plan.block_f), interpret=interpret
-    )
+    a, bmp = kops.relu_with_bitmap(h, (plan.block_m, plan.block_f))
     if act == "relu2":
         a = a * a  # same zero pattern: the bitmap stays valid
     gplan = sasa.bitmap_gated_plan(
         x.shape[0], w_in.shape[1], w_out.shape[1],
         block_m=plan.block_m, block_k=plan.block_f, block_n=plan.block_n,
     )
-    y = kops.sparce_gemm(
-        a, w_out, gplan, lhs_bitmap=bmp, out_dtype=x.dtype,
-        interpret=interpret,
-    )
+    y = kops.sparce_gemm(a, w_out, gplan, lhs_bitmap=bmp, out_dtype=x.dtype)
     return y, bmp.bits
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _sparce_mlp(x, w_in, w_out, plan, act, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _sparce_mlp(x, w_in, w_out, plan, act):
     if plan.variant == "fused":
-        return _fused_mlp_run(x, w_in, w_out, plan, act, interpret)
+        return _fused_mlp_run(x, w_in, w_out, plan, act)
     if plan.variant == "two_kernel":
-        return two_kernel_mlp(x, w_in, w_out, plan, act, interpret)
+        return two_kernel_mlp(x, w_in, w_out, plan, act)
     h = jnp.dot(x, w_in)
     a = jnp.maximum(h, 0.0)
     if act == "relu2":
@@ -245,12 +234,12 @@ def _sparce_mlp(x, w_in, w_out, plan, act, interpret):
     return jnp.dot(a, w_out), bits
 
 
-def _mlp_fwd_vjp(x, w_in, w_out, plan, act, interpret):
-    out = _sparce_mlp(x, w_in, w_out, plan, act, interpret)
+def _mlp_fwd_vjp(x, w_in, w_out, plan, act):
+    out = _sparce_mlp(x, w_in, w_out, plan, act)
     return out, (x, w_in, w_out)
 
 
-def _mlp_bwd_vjp(plan, act, interpret, res, cts):
+def _mlp_bwd_vjp(plan, act, res, cts):
     g, _ = cts  # no cotangent flows into the int32 bitmap
     x, w_in, w_out = res
     h = jnp.dot(
@@ -300,7 +289,7 @@ def sparce_mlp(
         dtype=str(x.dtype),
         block_m=cfg.block_m, block_f=cfg.block_k, block_n=cfg.block_n,
     )
-    y, bits = _sparce_mlp(x, w_in, w_out, plan, act, cfg.interpret)
+    y, bits = _sparce_mlp(x, w_in, w_out, plan, act)
     return y, bits, plan
 
 
@@ -313,15 +302,12 @@ def sparce_mlp(
 # Backward runs the exact (undropped) reference GLU gradient, so the op
 # stays trainable at any threshold.
 
-def unfused_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau,
-                    mode="kernel", interpret=True):
+def unfused_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau, mode="kernel"):
     """The pre-fused GLU pipeline the planner falls back to: dense gate
     + up GEMMs, threshold bitmap at the gate's writeback, bitmap-gated
     down-projection (compute skip only; six HBM round trips of the
     intermediate -- what the fused variant eliminates). Shared by the
     fused-mode fallback and the benchmarks. Returns (y, bits)."""
-    from repro.kernels import ops as kops
-
     g = jnp.dot(x, w_gate)
     ga = kref.glu_act_ref(g, act)
     bits = kref.gate_bitmap_ref(ga, (plan.block_m, plan.block_f), tau)
@@ -337,7 +323,6 @@ def unfused_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau,
     if mode == "kernel":
         y = kops.sparce_gemm(
             a, w_out, gplan, lhs_bitmap=bmp, out_dtype=x.dtype,
-            interpret=interpret,
         )
     else:
         y = kref.sparce_gemm_ref(
@@ -347,18 +332,16 @@ def unfused_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau,
     return y, bits
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _sparce_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _sparce_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau):
     if plan.variant == "fused":
         y, bmp = kops.sparce_glu_mlp_fused(
             x, w_gate, w_in, w_out, block_m=plan.block_m,
-            block_f=plan.block_f, act=act, tau=tau, interpret=interpret,
+            block_f=plan.block_f, act=act, tau=tau,
         )
         return y, bmp.bits
     if plan.variant == "unfused":
-        return unfused_glu_mlp(
-            x, w_gate, w_in, w_out, plan, act, tau, interpret=interpret
-        )
+        return unfused_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau)
     # dense fallback: plain GLU; the bitmap still rides along (report
     # only -- the caller must not count it as realized skips).
     g = jnp.dot(x, w_gate)
@@ -369,12 +352,12 @@ def _sparce_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau, interpret):
     return jnp.dot(a, w_out), bits
 
 
-def _glu_mlp_fwd_vjp(x, w_gate, w_in, w_out, plan, act, tau, interpret):
-    out = _sparce_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau, interpret)
+def _glu_mlp_fwd_vjp(x, w_gate, w_in, w_out, plan, act, tau):
+    out = _sparce_glu_mlp(x, w_gate, w_in, w_out, plan, act, tau)
     return out, (x, w_gate, w_in, w_out)
 
 
-def _glu_mlp_bwd_vjp(plan, act, tau, interpret, res, cts):
+def _glu_mlp_bwd_vjp(plan, act, tau, res, cts):
     gy, _ = cts  # no cotangent flows into the int32 bitmap
     x, w_gate, w_in, w_out = res
     xf = x.astype(jnp.float32)
@@ -429,7 +412,6 @@ def sparce_glu_mlp(
     )
     y, bits = _sparce_glu_mlp(
         x, w_gate, w_in, w_out, plan, act, float(cfg.gate_threshold),
-        cfg.interpret,
     )
     return y, bits, plan
 
@@ -489,9 +471,7 @@ def relu_with_bitmap(
     if not cfg.enabled or cfg.mode == "off":
         return jnp.maximum(x, 0), None
     if cfg.mode == "kernel":
-        y2, bmp = kops.relu_with_bitmap(
-            x2, (cfg.block_m, cfg.block_k), interpret=cfg.interpret
-        )
+        y2, bmp = kops.relu_with_bitmap(x2, (cfg.block_m, cfg.block_k))
         return y2.reshape(shape), bmp
     y2 = jnp.maximum(x2, 0)
     return y2.reshape(shape), sprf.compute_bitmap(y2, (cfg.block_m, cfg.block_k))

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for SparCE (validated via interpret=True on CPU).
+"""Pallas TPU kernels for SparCE (compiled on a TPU, interpreted on CPU).
 
 Modules: sparce_gemm (gated/compacted GEMM), sparce_mlp (fused MLP
 megakernel), paged_decode_attn (fetch-skipping decode attention over the

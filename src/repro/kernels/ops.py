@@ -11,6 +11,10 @@ block table), and the fetch-skipping machinery -- null-block table
 entries, index-map clamps, dead-stripe bitmaps -- works shard-locally
 without modification because it never assumes anything about the global
 array. tests/test_spmd_serving.py NaN-poison-proves this per shard.
+
+Whether a kernel is compiled or interpreted is decided here and nowhere
+else: :func:`interpret_mode` reads the backend, so a TPU always runs the
+compiled kernels and only the CPU backend interprets them.
 """
 from __future__ import annotations
 
@@ -26,6 +30,17 @@ from repro.kernels import sparce_gemm as _sg
 from repro.kernels import relu_bitmap as _rb
 from repro.kernels import sparce_glu_mlp as _sgm
 from repro.kernels import sparce_mlp as _sm
+
+
+# TPU vector registers are 128 lanes wide: a DMA'd weight stripe must
+# span whole lane tiles.
+_LANES = 128
+
+
+def interpret_mode() -> bool:
+    """Pallas kernels run compiled on a TPU and interpreted on the CPU
+    backend; this is the one place that makes that decision."""
+    return jax.default_backend() == "cpu"
 
 
 def _ceil_to(v: int, q: int) -> int:
@@ -46,13 +61,8 @@ def sparce_gemm(
     lhs_bitmap: Optional[TileBitmap] = None,
     rhs_bitmap: Optional[TileBitmap] = None,
     out_dtype=None,
-    interpret: bool = True,
 ) -> jax.Array:
-    """y[M,N] = x[M,K] @ w[K,N] under ``plan``, dropping gated tiles.
-
-    interpret=True is the CPU-validation mode; on a real TPU deployment
-    the same call sites set interpret=False.
-    """
+    """y[M,N] = x[M,K] @ w[K,N] under ``plan``, dropping gated tiles."""
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
@@ -73,6 +83,7 @@ def sparce_gemm(
             )
         return bits
 
+    interpret = interpret_mode()
     gate = plan.gate
     if gate == "none" or plan.variant == "dense":
         y = jnp.dot(
@@ -130,7 +141,6 @@ def sparce_mlp_fused(
     block_f: int,
     act: str = "relu",
     out_dtype=None,
-    interpret: bool = True,
 ) -> tuple[jax.Array, TileBitmap]:
     """Padded wrapper over the fused MLP megakernel.
 
@@ -139,6 +149,7 @@ def sparce_mlp_fused(
     same TileBitmap the two-kernel path would produce, so skip
     accounting is identical. Padding rows/stripes are all-zero after the
     activation, so their bits are 1 and their w_out stripes never fetch.
+    N pads to whole lanes (zero output columns, sliced off).
     """
     m, k = x.shape
     k2, fdim = w_in.shape
@@ -147,10 +158,10 @@ def sparce_mlp_fused(
     pm, pf = _ceil_to(m, block_m), _ceil_to(fdim, block_f)
     xp = _pad2(x, pm, k)
     winp = _pad2(w_in, k, pf)
-    woutp = _pad2(w_out, pf, n)
+    woutp = _pad2(w_out, pf, _ceil_to(n, _LANES))
     y, bits = _sm.sparce_mlp_fused(
         xp, winp, woutp, block_m=block_m, block_f=block_f, act=act,
-        out_dtype=out_dtype, interpret=interpret,
+        out_dtype=out_dtype, interpret=interpret_mode(),
     )
     return y[:m, :n], TileBitmap(
         bits=bits, block=(block_m, block_f), shape=(m, fdim)
@@ -168,7 +179,6 @@ def sparce_glu_mlp_fused(
     act: str = "silu",
     tau: float = 0.0,
     out_dtype=None,
-    interpret: bool = True,
 ) -> tuple[jax.Array, TileBitmap]:
     """Padded wrapper over the gated-GLU megakernel.
 
@@ -178,7 +188,8 @@ def sparce_glu_mlp_fused(
     identical. Padding stripes see zero gate weights, act(0) == 0 and
     ``|0| <= tau``, so their bits are 1 and their w_in/w_out stripes are
     never fetched; padding rows can only vote "dead" and never flip a
-    real tile live.
+    real tile live. N pads to whole lanes (zero output columns, sliced
+    off).
     """
     m, k = x.shape
     kg, fg = w_gate.shape
@@ -190,10 +201,10 @@ def sparce_glu_mlp_fused(
     xp = _pad2(x, pm, k)
     wgatep = _pad2(w_gate, k, pf)
     winp = _pad2(w_in, k, pf)
-    woutp = _pad2(w_out, pf, n)
+    woutp = _pad2(w_out, pf, _ceil_to(n, _LANES))
     y, bits = _sgm.sparce_glu_mlp_fused(
         xp, wgatep, winp, woutp, block_m=block_m, block_f=block_f,
-        act=act, tau=tau, out_dtype=out_dtype, interpret=interpret,
+        act=act, tau=tau, out_dtype=out_dtype, interpret=interpret_mode(),
     )
     return y[:m, :n], TileBitmap(
         bits=bits, block=(block_m, block_f), shape=(m, fdim)
@@ -219,7 +230,6 @@ def paged_decode_attn(
     *,
     scale: Optional[float] = None,
     feat_align: int = 1,
-    interpret: bool = True,
 ) -> jax.Array:
     """Ragged-shape wrapper over the paged GQA decode kernel.
 
@@ -248,7 +258,7 @@ def paged_decode_attn(
         _pad_last(v_pool, feat_align),
         block_tables,
         jnp.minimum(lengths, block_tables.shape[1] * k_pool.shape[1]),
-        scale=scale, interpret=interpret,
+        scale=scale, interpret=interpret_mode(),
     )
     return out[..., :D]
 
@@ -263,7 +273,6 @@ def paged_mla_decode_attn(
     *,
     scale: float,
     feat_align: int = 1,
-    interpret: bool = True,
 ) -> jax.Array:
     """Ragged-shape wrapper over the paged MLA absorbed-decode kernel.
 
@@ -279,31 +288,18 @@ def paged_mla_decode_attn(
         _pad_last(kr_pool, feat_align),
         block_tables,
         jnp.minimum(lengths, block_tables.shape[1] * ckv_pool.shape[1]),
-        scale=scale, interpret=interpret,
+        scale=scale, interpret=interpret_mode(),
     )
     return out[..., :r]
 
 
-def relu_with_bitmap(
-    x: jax.Array, block, *, interpret: bool = True
-) -> tuple[jax.Array, TileBitmap]:
+def relu_with_bitmap(x: jax.Array, block) -> tuple[jax.Array, TileBitmap]:
     """Fused relu + SVC bitmap over a 2-D activation."""
     r, c = x.shape
     br, bc = block
     pr, pc = _ceil_to(r, br), _ceil_to(c, bc)
     xp = _pad2(x, pr, pc)
-    y, bits = _rb.relu_bitmap(xp, block_r=br, block_c=bc, interpret=interpret)
+    y, bits = _rb.relu_bitmap(
+        xp, block_r=br, block_c=bc, interpret=interpret_mode())
     return y[:r, :c], TileBitmap(bits=bits, block=(br, bc), shape=(r, c))
 
-
-def relu_bwd_with_bitmap(
-    x: jax.Array, g: jax.Array, block, *, interpret: bool = True
-) -> tuple[jax.Array, TileBitmap]:
-    r, c = x.shape
-    br, bc = block
-    pr, pc = _ceil_to(r, br), _ceil_to(c, bc)
-    xp, gp = _pad2(x, pr, pc), _pad2(g, pr, pc)
-    gx, bits = _rb.relu_bwd_bitmap(
-        xp, gp, block_r=br, block_c=bc, interpret=interpret
-    )
-    return gx[:r, :c], TileBitmap(bits=bits, block=(br, bc), shape=(r, c))

@@ -18,21 +18,48 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _relu_bitmap_kernel(x_ref, y_ref, bits_ref):
+def _relu_bitmap_kernel(x_ref, y_ref, bits_ref, *, nc: int):
     y = jnp.maximum(x_ref[...], 0)
     y_ref[...] = y.astype(y_ref.dtype)
-    # Writeback-fused SVC: reduce the tile we just produced to one bit.
-    bits_ref[0, 0] = jnp.where(
-        jnp.any(y > 0), jnp.int32(0), jnp.int32(1)
+    # Writeback-fused SVC: reduce the tile we just produced to one bit
+    # (reduced in f32: the chip moves only 32-bit values to scalars).
+    bits_ref[pl.program_id(0) * nc + pl.program_id(1)] = jnp.where(
+        jnp.max(y.astype(jnp.float32)) == 0, jnp.int32(1), jnp.int32(0)
     )
 
 
-def _relu_bwd_bitmap_kernel(x_ref, g_ref, gx_ref, bits_ref):
+def _relu_bwd_bitmap_kernel(x_ref, g_ref, gx_ref, bits_ref, *, nc: int):
     gx = jnp.where(x_ref[...] > 0, g_ref[...], jnp.zeros_like(g_ref[...]))
     gx_ref[...] = gx.astype(gx_ref.dtype)
-    bits_ref[0, 0] = jnp.where(
-        jnp.any(gx != 0), jnp.int32(0), jnp.int32(1)
+    bits_ref[pl.program_id(0) * nc + pl.program_id(1)] = jnp.where(
+        jnp.max(jnp.abs(gx).astype(jnp.float32)) == 0, jnp.int32(1), jnp.int32(0)
     )
+
+
+def _tiled_call(kernel, operands, out_dtype, block_r, block_c, interpret):
+    """Elementwise tile pass + one bit per tile.
+
+    Rows go through an (nr, block_r, c) view with the tile axis squeezed,
+    so any block_r compiles; the bitmap is one flat SMEM array written at
+    i * nc + j (block_c must be a multiple of 128 when compiled)."""
+    r, c = operands[0].shape
+    if r % block_r or c % block_c:
+        raise ValueError(
+            f"padded dims required: {(r, c)} % ({block_r},{block_c})")
+    nr, nc = r // block_r, c // block_c
+    tile = pl.BlockSpec((None, block_r, block_c), lambda i, j: (i, 0, j))
+    y, bits = pl.pallas_call(
+        functools.partial(kernel, nc=nc),
+        grid=(nr, nc),
+        in_specs=[tile] * len(operands),
+        out_specs=[tile, pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_shape=[
+            jax.ShapeDtypeStruct((nr, block_r, c), out_dtype),
+            jax.ShapeDtypeStruct((nr * nc,), jnp.int32),
+        ],
+        interpret=interpret,
+    )(*(o.reshape(nr, block_r, c) for o in operands))
+    return y.reshape(r, c), bits.reshape(nr, nc)
 
 
 @functools.partial(
@@ -42,26 +69,8 @@ def relu_bitmap(
     x: jax.Array, *, block_r: int, block_c: int, interpret: bool = False
 ):
     """Returns (relu(x), bits) with bits int32[r/block_r, c/block_c]."""
-    r, c = x.shape
-    if r % block_r or c % block_c:
-        raise ValueError(f"padded dims required: {x.shape} % ({block_r},{block_c})")
-    nr, nc = r // block_r, c // block_c
-    return pl.pallas_call(
-        _relu_bitmap_kernel,
-        grid=(nr, nc),
-        in_specs=[pl.BlockSpec((block_r, block_c), lambda i, j: (i, j))],
-        out_specs=[
-            pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec(
-                (1, 1), lambda i, j: (i, j), memory_space=pltpu.SMEM
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, c), x.dtype),
-            jax.ShapeDtypeStruct((nr, nc), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x)
+    return _tiled_call(
+        _relu_bitmap_kernel, (x,), x.dtype, block_r, block_c, interpret)
 
 
 @functools.partial(
@@ -72,27 +81,7 @@ def relu_bwd_bitmap(
     interpret: bool = False,
 ):
     """Returns (g * (x > 0), error-bits) -- fused error-sparsity writeback."""
-    r, c = x.shape
     assert g.shape == x.shape
-    if r % block_r or c % block_c:
-        raise ValueError(f"padded dims required: {x.shape} % ({block_r},{block_c})")
-    nr, nc = r // block_r, c // block_c
-    return pl.pallas_call(
-        _relu_bwd_bitmap_kernel,
-        grid=(nr, nc),
-        in_specs=[
-            pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_r, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec(
-                (1, 1), lambda i, j: (i, j), memory_space=pltpu.SMEM
-            ),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, c), g.dtype),
-            jax.ShapeDtypeStruct((nr, nc), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x, g)
+    return _tiled_call(
+        _relu_bwd_bitmap_kernel, (x, g), g.dtype, block_r, block_c,
+        interpret)

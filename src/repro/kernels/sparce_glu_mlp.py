@@ -68,6 +68,7 @@ def _glu_mlp_kernel(
     *, nf: int, block_f: int, act: str, tau: float,
 ):
     """One grid step: gate tile f of row-tile i, bit, gated up+down proj."""
+    i = pl.program_id(0)
     f = pl.program_id(1)
     slot = jax.lax.rem(f, 2)
     prev = jax.lax.rem(f + 1, 2)
@@ -86,8 +87,8 @@ def _glu_mlp_kernel(
     ga = _gate_act_f32(g, act).astype(x_ref.dtype).astype(jnp.float32)
     # -- SpRF bit at the gate's writeback: near-zero gate => dead tile.
     # `<=` makes tau=0 the exact all-zero test (relu-gated exactness).
-    bit = jnp.where(jnp.all(jnp.abs(ga) <= tau), jnp.int32(1), jnp.int32(0))
-    bits_ref[0, 0] = bit
+    bit = jnp.where(jnp.max(jnp.abs(ga)) <= tau, jnp.int32(1), jnp.int32(0))
+    bits_ref[i * nf + f] = bit
     ga_sc[slot] = ga
     bit_sc[slot] = bit
 
@@ -162,8 +163,9 @@ def sparce_glu_mlp_fused(
 ):
     """(act(x @ w_gate) * (x @ w_in)) @ w_out in one kernel.
 
-    x: (M, K); w_gate, w_in: (K, F); w_out: (F, N). M % block_m == 0 and
-    F % block_f == 0 required (use ops.sparce_glu_mlp_fused for padding).
+    x: (M, K); w_gate, w_in: (K, F); w_out: (F, N). M % block_m == 0,
+    F % block_f == 0 and, compiled, N % 128 == 0 are required (the w_out
+    stripe DMA must be lane-aligned; ops.sparce_glu_mlp_fused pads).
     Returns (y, bits); bits: int32[M/block_m, F/block_f], 1 == every
     ``|act(g)|`` in the tile is <= tau -- identical semantics to the
     unfused gate-thresholding path, so skip accounting matches exactly.
@@ -188,24 +190,29 @@ def sparce_glu_mlp_fused(
     kernel = functools.partial(
         _glu_mlp_kernel, nf=nf, block_f=block_f, act=act, tau=float(tau)
     )
+    # Row tiles are addressed through an (nm, block_m, K) view with the
+    # tile axis squeezed: a block's trailing dims then equal the array's,
+    # which the TPU compiler accepts for any block_m (one decode slot per
+    # tile included). The bitmap is one flat SMEM array, written at
+    # i * nf + f and copied back once.
     y, bits = pl.pallas_call(
         kernel,
         grid=(nm, nf),
         in_specs=[
-            pl.BlockSpec((block_m, k), lambda i, f: (i, 0)),
+            pl.BlockSpec((None, block_m, k), lambda i, f: (i, 0, 0)),
             # The gate weights always stream: they are the predictor.
             pl.BlockSpec((k, block_f), lambda i, f: (0, f)),
             # w_in and w_out stay in HBM; the kernel DMAs live stripes only.
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((block_m, n), lambda i, f: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, f: (i, f), memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, block_m, n), lambda i, f: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, n), out_dtype),
-            jax.ShapeDtypeStruct((nm, nf), jnp.int32),
+            jax.ShapeDtypeStruct((nm, block_m, n), out_dtype),
+            jax.ShapeDtypeStruct((nm * nf,), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, block_m, block_f), jnp.float32),  # act(g) tiles
@@ -216,5 +223,5 @@ def sparce_glu_mlp_fused(
             pltpu.SemaphoreType.DMA((2, 2)),  # (slot, win/wout)
         ],
         interpret=interpret,
-    )(x, w_gate, w_in, w_out)
-    return y, bits
+    )(x.reshape(nm, block_m, k), w_gate, w_in, w_out)
+    return y.reshape(m, n), bits.reshape(nm, nf)

@@ -48,6 +48,7 @@ def _fused_mlp_kernel(
     *, nf: int, block_f: int, act: str,
 ):
     """One grid step: up-proj tile f of row-tile i, bit, gated down-proj."""
+    i = pl.program_id(0)
     f = pl.program_id(1)
     slot = jax.lax.rem(f, 2)
     prev = jax.lax.rem(f + 1, 2)
@@ -65,8 +66,8 @@ def _fused_mlp_kernel(
     # writeback would -- keeps the fused kernel bit-compatible with the
     # reference contract in low precision (the tile still lives in VMEM).
     a = a.astype(x_ref.dtype).astype(jnp.float32)
-    bit = jnp.where(jnp.any(a != 0.0), jnp.int32(0), jnp.int32(1))
-    bits_ref[0, 0] = bit
+    bit = jnp.where(jnp.max(a) == 0.0, jnp.int32(1), jnp.int32(0))
+    bits_ref[i * nf + f] = bit
     a_sc[slot] = a
     bit_sc[slot] = bit
 
@@ -121,8 +122,9 @@ def sparce_mlp_fused(
 ):
     """(act(x @ w_in)) @ w_out in one kernel; returns (y, bits).
 
-    x: (M, K); w_in: (K, F); w_out: (F, N). M % block_m == 0 and
-    F % block_f == 0 are required (use ops.sparce_mlp_fused for padding).
+    x: (M, K); w_in: (K, F); w_out: (F, N). M % block_m == 0,
+    F % block_f == 0 and, compiled, N % 128 == 0 are required (the w_out
+    stripe DMA must be lane-aligned; ops.sparce_mlp_fused pads).
     bits: int32[M/block_m, F/block_f], 1 == activated tile all-zero --
     identical semantics to ``relu_bitmap`` over the intermediate, so the
     aux skip accounting matches the two-kernel path exactly.
@@ -143,22 +145,24 @@ def sparce_mlp_fused(
     kernel = functools.partial(
         _fused_mlp_kernel, nf=nf, block_f=block_f, act=act
     )
+    # Row tiles via an (nm, block_m, K) view with the tile axis squeezed
+    # (any block_m compiles); the bitmap is one flat SMEM array.
     y, bits = pl.pallas_call(
         kernel,
         grid=(nm, nf),
         in_specs=[
-            pl.BlockSpec((block_m, k), lambda i, f: (i, 0)),
+            pl.BlockSpec((None, block_m, k), lambda i, f: (i, 0, 0)),
             pl.BlockSpec((k, block_f), lambda i, f: (0, f)),
             # w_out stays in HBM; the kernel DMAs only the live stripes.
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((block_m, n), lambda i, f: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, f: (i, f), memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, block_m, n), lambda i, f: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, n), out_dtype),
-            jax.ShapeDtypeStruct((nm, nf), jnp.int32),
+            jax.ShapeDtypeStruct((nm, block_m, n), out_dtype),
+            jax.ShapeDtypeStruct((nm * nf,), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, block_m, block_f), jnp.float32),  # a tiles
@@ -168,5 +172,5 @@ def sparce_mlp_fused(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
-    )(x, w_in, w_out)
-    return y, bits
+    )(x.reshape(nm, block_m, k), w_in, w_out)
+    return y.reshape(m, n), bits.reshape(nm, nf)

@@ -9,18 +9,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-
-def _axis_types_kw(n_axes: int) -> dict:
-    """axis_types=Auto on jax versions that have it (>=0.5), else nothing.
-
-    jax 0.4.x meshes are implicitly fully-auto, so omitting the kwarg is
-    semantically identical there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -35,7 +24,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             "(dry-run must set --xla_force_host_platform_device_count=512)"
         )
     return jax.make_mesh(
-        shape, axes, devices=devices[:n], **_axis_types_kw(len(axes))
+        shape, axes, devices=devices[:n],
+        axis_types=(AxisType.Auto,) * len(axes),
     )
 
 
@@ -43,5 +33,6 @@ def make_mesh(shape, axes):
     """Arbitrary small mesh for tests (e.g. (2, 4) on 8 stub devices)."""
     n = math.prod(shape)
     return jax.make_mesh(
-        shape, axes, devices=jax.devices()[:n], **_axis_types_kw(len(axes))
+        shape, axes, devices=jax.devices()[:n],
+        axis_types=(AxisType.Auto,) * len(axes),
     )

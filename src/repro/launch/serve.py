@@ -74,12 +74,13 @@ first: XLA_FLAGS=--xla_force_host_platform_device_count=8.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import numpy as np
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=8)
@@ -156,25 +157,24 @@ def main(argv=None):
                          "pool over DP data shards and heads/FFN over TP "
                          "model shards (needs DPxTP visible devices and "
                          "--kv-block-size > 0)")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    import jax
 
+def build_config(args: argparse.Namespace):
+    """(arch config, ServeConfig) for parsed launcher arguments."""
     from repro.configs import get_config
     from repro.core.sparse_ops import SparsityConfig
     from repro.runtime.scheduler import SLOConfig
-    from repro.models import model as model_lib
-    from repro.runtime.server import (
-        AsyncServer, Request, ServeConfig, Server,
-    )
+    from repro.runtime.server import ServeConfig
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    dp, tp = ((int(x) for x in args.mesh.split(","))
+              if args.mesh is not None else (1, 1))
     sparsity = None
     sparce_on = args.sparce or args.sparce_gate_threshold is not None
     if sparce_on:
-        import dataclasses
         glu_arch = cfg.mlp_act in ("silu", "gelu")
         if glu_arch and args.sparce_gate_threshold is not None:
             # Gated-GLU path: KEEP the arch's activation; sparsity comes
@@ -186,21 +186,21 @@ def main(argv=None):
             # act BEFORE init (relu MLPs are 2-matrix, no w_gate).
             cfg = dataclasses.replace(cfg, mlp_act="relu")
             tau = 0.0
-        # block_m=1: decode rows are slots, so per-row tiles make each
-        # freed slot's GEMM work individually skippable. Under --mesh
-        # the f-tile must divide the PER-SHARD d_ff slice (the engine
-        # rejects tilings that would break exact skip-stat parity).
+        # block_m: one row tile covers the slots a data shard decodes
+        # (at most one 8-row sublane tile), so a decode step streams each
+        # weight stripe once and a tile is dead only when all of its
+        # slots are. Under --mesh the f-tile must divide the PER-SHARD
+        # d_ff slice (the engine rejects tilings that would break exact
+        # skip-stat parity).
+        block_m = max(1, min(8, args.batch_slots // dp))
         block_k = 128
-        if args.mesh is not None:
-            tp = int(args.mesh.split(",")[1])
-            while (cfg.d_ff // max(tp, 1)) % block_k:
-                block_k //= 2
+        while tp > 1 and (cfg.d_ff // tp) % block_k:
+            block_k //= 2
         sparsity = SparsityConfig(
-            enabled=True, mode=args.sparce_mode, block_m=1,
+            enabled=True, mode=args.sparce_mode, block_m=block_m,
             block_k=block_k,
             autotune=args.sparce_autotune, gate_threshold=tau,
         )
-    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed))
     buckets = None
     if args.prefill_buckets is not None:
         buckets = (
@@ -218,10 +218,6 @@ def main(argv=None):
                               if args.slo_itl_ticks is not None
                               else defaults.target_itl_ticks),
         )
-    mesh = None
-    if args.mesh is not None:
-        dp, tp = (int(x) for x in args.mesh.split(","))
-        mesh = (dp, tp)
     serve_cfg = ServeConfig(
         batch_slots=args.batch_slots, max_len=args.max_len,
         temperature=args.temperature, eos_id=args.eos_id,
@@ -229,7 +225,14 @@ def main(argv=None):
         kv_block_size=args.kv_block_size,
         kv_pool_blocks=args.kv_pool_blocks,
         prefill_buckets=buckets, attn_kernel=args.attn_kernel,
-        prefix_cache=args.prefix_cache, slo=slo, mesh=mesh)
+        prefix_cache=args.prefix_cache, slo=slo,
+        mesh=(dp, tp) if args.mesh is not None else None)
+    return cfg, serve_cfg
+
+
+def make_requests(args: argparse.Namespace, cfg) -> list:
+    """The seeded workload: same arguments, same prompts and budgets."""
+    from repro.runtime.server import Request
 
     rng = np.random.default_rng(args.seed)
     shared_prefix = None
@@ -259,6 +262,22 @@ def main(argv=None):
         if shared_prefix is not None:
             prompt = np.concatenate([shared_prefix, prompt], axis=-1)
         reqs.append(Request(uid=i, prompt=prompt, max_new=max_new))
+    return reqs
+
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    import jax
+
+    from repro.models import model as model_lib
+    from repro.runtime.server import AsyncServer, Server
+
+    cfg, serve_cfg = build_config(args)
+    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed))
+    reqs = make_requests(args, cfg)
+    # Open-loop arrival gaps draw from their own seeded stream.
+    rng = np.random.default_rng([args.seed, 1])
 
     if args.open_loop:
         # Live-queue path: Poisson-spaced submissions against the
@@ -286,7 +305,8 @@ def main(argv=None):
     # attribute reads fail loudly on a typo instead of defaulting to 0.
     tok = m.decode_tokens
     print(f"served {len(done)} requests, {tok} decode tokens in "
-          f"{m.ticks} ticks, {dt:.2f}s ({tok / max(dt, 1e-9):.1f} tok/s)")
+          f"{m.ticks} ticks, {dt:.2f}s host wall clock incl. compile "
+          f"({tok / max(dt, 1e-9):.1f} tok/s)")
     if m.mesh_data or m.mesh_model:
         print(f"  SPMD mesh: data={int(m.mesh_data)} x "
               f"model={int(m.mesh_model)} "
@@ -294,7 +314,8 @@ def main(argv=None):
               f"skip stats below are psum'd across shards)")
     occ = tok / max(1, m.ticks * args.batch_slots)
     print(f"  slot occupancy {occ:.2f}, prefill {m.prefill_tokens} tok "
-          f"/ {m.prefill_s:.2f}s, decode {m.decode_s:.2f}s")
+          f"/ {m.prefill_s:.2f}s, decode {m.decode_s:.2f}s (host clock "
+          f"incl. compile)")
     if m.total_tile_dots:
         print(f"  SparCE mlp_skip_fraction={m.mlp_skip_fraction:.3f} "
               f"({m.skipped_tile_dots:.0f}/{m.total_tile_dots:.0f} "
@@ -324,7 +345,8 @@ def main(argv=None):
                   f"{int(m.attn_blocks_total)} pool-block fetches "
                   f"(skip {m.attn_block_skip_fraction:.1%}); "
                   f"{(m.attn_bytes_gather - m.attn_bytes_paged)/1e6:.2f}"
-                  f" MB HBM {realized} vs full-view gather")
+                  f" MB modeled HBM bytes {realized} vs full-view "
+                  "gather")
     if m.prefix_cache_enabled:
         print(f"  prefix cache: {int(m.prefix_hits)}/"
               f"{int(m.prefix_lookups)} admissions hit "
@@ -337,7 +359,7 @@ def main(argv=None):
               f"{m.prefill_ticks_saved:.2f}/{m.prefill_ticks_nocache:.2f} "
               f"prefill ticks ({m.prefill_ticks_saved_frac:.1%}), "
               f"{m.prefill_flops_saved/1e9:.2f} GFLOP of prefill skipped")
-    if args.open_loop or slo is not None:
+    if args.open_loop or serve_cfg.slo is not None:
         print(f"  queue: depth peak {int(m.queue_depth_peak)}, "
               f"admission {int(m.sched_admitted)} admitted / "
               f"{int(m.sched_deferred)} deferred / "
@@ -358,4 +380,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

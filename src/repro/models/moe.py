@@ -29,7 +29,6 @@ from repro.configs.base import ArchConfig
 from repro.models import modules as nn
 from repro.parallel.sharding import constrain, current_mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def moe_init(key, cfg: ArchConfig, dtype):
@@ -291,9 +290,9 @@ def _moe_forward_ep(
         P(data_axes, None, None),
     )
     out_specs = (P(data_axes, None, None), P(), P())
-    y, aux, slot_sparsity = shard_map(
+    y, aux, slot_sparsity = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(params["router"], params["w_in"], params["w_gate"], params["w_out"],
       shared, x)
     return y, aux, slot_sparsity

@@ -18,7 +18,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(
@@ -92,10 +91,10 @@ def pipeline_forward(
             outs = jax.lax.all_gather(outs, axis)[n_stages - 1]
         return outs
 
-    out = shard_map(
+    out = jax.shard_map(
         spmd, mesh=mesh,
         in_specs=(p_params, extra_specs or P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, micros)
     return out.reshape(x.shape)

@@ -18,7 +18,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeConfig
 
@@ -233,7 +233,12 @@ def serve_mesh(shape: Tuple[int, int]) -> Mesh:
             " -- on CPU set XLA_FLAGS=--xla_force_host_platform_device_"
             "count=<n> before the first jax import (see launch/dryrun.py)"
         )
-    return jax.make_mesh((dp, tp), SERVE_AXES, devices=devices[:n])
+    # Auto axes: the engine's plain GSPMD pool ops (insert/copy/prefix
+    # gather) leave the partitioning to the compiler.
+    return jax.make_mesh(
+        (dp, tp), SERVE_AXES, devices=devices[:n],
+        axis_types=(AxisType.Auto,) * 2,
+    )
 
 
 def serve_local_config(cfg: ArchConfig, tp: int) -> ArchConfig:
@@ -323,11 +328,11 @@ def serve_prefill_cache_specs(cfg: ArchConfig) -> Any:
 
 
 def constrain(x: jax.Array, spec: P) -> jax.Array:
-    """Sharding-constraint hook (used by §Perf iterations)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError):
+    """Sharding constraint under the ambient mesh; a no-op without one
+    (single-device model code shares this call site)."""
+    if current_mesh() is None:
         return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def current_mesh() -> Optional[Mesh]:
@@ -335,11 +340,7 @@ def current_mesh() -> Optional[Mesh]:
 
     Model code (MoE expert parallelism) consults this at trace time to
     decide whether the shard_map fast path is available."""
-    try:
-        from jax._src import mesh as mesh_src  # noqa: PLC0415
-        m = mesh_src.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:  # noqa: BLE001
-        pass
-    return None
+    from jax._src import mesh as mesh_src  # noqa: PLC0415
+
+    m = mesh_src.thread_resources.env.physical_mesh
+    return None if m.empty else m

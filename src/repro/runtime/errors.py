@@ -21,6 +21,10 @@ instead of parsing message strings:
   attributed to one request (a fused-tick exception poisons every
   slot's logits). This is the fail-all path: all outstanding
   submissions fail and the engine stops.
+* :class:`DeviceProgramError` -- an :class:`EngineFatal` raised when a
+  jitted step program fails to trace, lower, compile or run (a kernel
+  the chip's compiler refuses, a device fault). The same program serves
+  every request of its shape, so such a failure is never one request's.
 
 The split mirrors the paper's squash-vs-skip argument at the failure
 level: request-scoped containment "squashes" only the instructions that
@@ -33,6 +37,9 @@ request escalates to the fatal path rather than silently corrupting
 neighbours.
 """
 from __future__ import annotations
+
+import contextlib
+from typing import Iterator
 
 
 class ServingError(RuntimeError):
@@ -62,6 +69,29 @@ class EngineFatal(ServingError):
     stops. The original fault is chained as ``__cause__``."""
 
 
+class DeviceProgramError(EngineFatal):
+    """A jitted step program failed to trace, lower, compile or run.
+    The original error is chained as ``__cause__``."""
+
+
+@contextlib.contextmanager
+def device_program(name: str) -> Iterator[None]:
+    """Run a step program's call (and the host reads of its results)
+    so that any failure escalates as :class:`DeviceProgramError`.
+
+    Lowering errors surface as plain ``ValueError``s from inside the
+    jit call; without this boundary they would read as prompt-shaped
+    problems and fail only the request that happened to compile first.
+    """
+    try:
+        yield
+    except ServingError:
+        raise
+    except Exception as e:  # noqa: BLE001 - re-raised as engine-fatal
+        raise DeviceProgramError(
+            f"{name} program failed: {type(e).__name__}: {e}") from e
+
+
 def is_request_scoped(exc: BaseException) -> bool:
     """Classify an exception raised while handling ONE request's work
     (its prefill, its block growth): can the engine contain it to that
@@ -71,9 +101,10 @@ def is_request_scoped(exc: BaseException) -> bool:
     explicitly, and ``ValueError``/``FloatingPointError`` from a
     single-request host path are prompt-shaped problems (malformed
     input, bad lengths) that cannot have touched other slots' state.
-    Everything else -- allocator invariant violations, JAX runtime
-    errors from fused multi-slot computations, MemoryError -- escalates
-    to the engine-fatal fail-all path.
+    Everything else -- failed step programs (:func:`device_program`),
+    ``jax.errors.JaxRuntimeError`` (a ``RuntimeError``), allocator
+    invariant violations, MemoryError -- escalates to the engine-fatal
+    fail-all path.
     """
     from repro.runtime.faults import InjectedFault  # leaf-local import
 

@@ -93,7 +93,6 @@ from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -104,7 +103,7 @@ from repro.core.sparse_ops import SparsityConfig
 from repro.models import model as model_lib
 from repro.runtime.errors import (
     EngineFatal, RequestCancelled, RequestFailed, ServerOverloaded,
-    ServingError, is_request_scoped,
+    ServingError, device_program, is_request_scoped,
 )
 from repro.runtime.faults import FaultInjector, FaultPlan, InjectedFault
 from repro.runtime.metrics import ServeMetrics
@@ -708,12 +707,12 @@ class Server:
             return logits, new_caches, jax.lax.psum(
                 skip, ("data", "model"))
 
-        self._decode = jax.jit(shard_map(
+        self._decode = jax.jit(jax.shard_map(
             _decode_body, mesh=mesh,
             in_specs=(pspecs, P("data"), pool_specs, P("data"),
                       P("data")),
             out_specs=(P("data"), pool_specs, P()),
-            check_rep=False,
+            check_vma=False,
         ))
 
         def _prefill_body(p, batch):
@@ -731,11 +730,11 @@ class Server:
             return logits, new_caches, jax.lax.psum(
                 aux["skip"], ("model",))
 
-        self._prefill = jax.jit(shard_map(
+        self._prefill = jax.jit(jax.shard_map(
             _prefill_body, mesh=mesh,
             in_specs=(pspecs, P()),
             out_specs=(P(), small_specs, P()),
-            check_rep=False,
+            check_vma=False,
         ))
 
         if serve_cfg.prefix_cache:
@@ -749,11 +748,11 @@ class Server:
                 return logits, new_caches, jax.lax.psum(
                     aux["skip"], ("model",))
 
-            suffix_sm = shard_map(
+            suffix_sm = jax.shard_map(
                 _suffix_body, mesh=mesh,
                 in_specs=(pspecs, P(), small_specs),
                 out_specs=(P(), small_specs, P()),
-                check_rep=False,
+                check_vma=False,
             )
 
             def _prefill_cached_fn(p, batch, pool, block_ids,
@@ -830,9 +829,9 @@ class Server:
 
         The prompt is padded up to its bucket (masked-tail positions):
         the cache length still advances by the TRUE length and logits are
-        gathered at the last real position, so the result is bit-for-bit
-        the exact-length prefill while the jit trace count stays bounded
-        by ``len(buckets)``.
+        gathered at the last real position, so the result is the
+        exact-length prefill up to float rounding while the jit trace
+        count stays bounded by ``len(buckets)``.
         """
         cfg = self.cfg
         prompt = self._effective_prompt(r)
@@ -856,12 +855,7 @@ class Server:
                 (1, cfg.num_patches, cfg.d_model),
                 jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32,
             )
-        t0 = time.perf_counter()
-        logits, small, skip = self._prefill(self.params, batch)
-        # Host-side trace ledger: one entry per (jitted fn, shape), so it
-        # counts replan retraces too and stays a faithful fallback if the
-        # jit-cache probe (_cache_size, a private JAX API) ever goes away.
-        self._prefill_shapes.add((id(self._prefill), cfg.frontend, S_pad))
+        ids = None
         if self._paged:
             ids = np.zeros((self._max_blocks,), np.int32)
             ids[: len(block_ids)] = block_ids
@@ -870,18 +864,27 @@ class Server:
             # stays shard-LOCAL. Padding zeros shift to the owner's
             # null block (base + 0), whose content is never read.
             ids += self._slot_base(slot)
-            caches = model_lib.insert_slot_paged(
-                caches, small, jnp.int32(slot), jnp.asarray(ids),
-                jnp.int32(rows0),
-            )
-        else:
-            caches = model_lib.insert_slot_caches(caches, small, slot)
+        t0 = time.perf_counter()
+        with device_program("prefill"):
+            logits, small, skip = self._prefill(self.params, batch)
+            if ids is not None:
+                caches = model_lib.insert_slot_paged(
+                    caches, small, jnp.int32(slot), jnp.asarray(ids),
+                    jnp.int32(rows0),
+                )
+            else:
+                caches = model_lib.insert_slot_caches(caches, small, slot)
+            # last_only logits: (1, 1, V) or (1, 1, K, V) for codes.
+            last = np.asarray(logits[0, 0], np.float32)  # (V,) or (K, V)
+            skip = np.asarray(skip, np.float64)
+        # Host-side trace ledger: one entry per (jitted fn, shape), so it
+        # counts replan retraces too and stays a faithful fallback if the
+        # jit-cache probe (_cache_size, a private JAX API) ever goes away.
+        self._prefill_shapes.add((id(self._prefill), cfg.frontend, S_pad))
         self.metrics.prefill_s += time.perf_counter() - t0
         self.metrics.prefill_tokens += S
         self.metrics.admitted += 1
         self._count_prefill_skip(skip)
-        # last_only logits: (1, 1, V) or (1, 1, K, V) for codes.
-        last = np.asarray(logits[0, 0], np.float32)  # (V,) or (K, V)
         return last, caches
 
     def _count_prefill_skip(self, skip) -> None:
@@ -931,23 +934,25 @@ class Server:
         # sharded pool: feed them GLOBAL flat ids (owner base + local).
         g_row = (np.asarray(table_row, np.int32)
                  + np.int32(self._slot_base(slot)))
-        logits, small, skip = self._prefill_cached(
-            self.params, batch, caches, jnp.asarray(g_row),
-            jnp.int32(prefix_len),
-        )
+        with device_program("suffix prefill"):
+            logits, small, skip = self._prefill_cached(
+                self.params, batch, caches, jnp.asarray(g_row),
+                jnp.int32(prefix_len),
+            )
+            caches = model_lib.insert_slot_paged_from(
+                caches, small, jnp.int32(slot), jnp.asarray(g_row),
+                jnp.int32(rows0), jnp.int32(prefix_len),
+            )
+            last = np.asarray(logits[0, 0], np.float32)  # (V,) or (K, V)
+            skip = np.asarray(skip, np.float64)
         self._prefill_shapes.add(
             (id(self._prefill_cached), cfg.frontend, S_pad))
-        caches = model_lib.insert_slot_paged_from(
-            caches, small, jnp.int32(slot), jnp.asarray(g_row),
-            jnp.int32(rows0), jnp.int32(prefix_len),
-        )
         self.metrics.prefill_s += time.perf_counter() - t0
         # Only the suffix actually prefilled; the matched rows are the
         # prefix_matched_tokens counter's business.
         self.metrics.prefill_tokens += n_suffix
         self.metrics.admitted += 1
         self._count_prefill_skip(skip)
-        last = np.asarray(logits[0, 0], np.float32)  # (V,) or (K, V)
         return last, caches
 
     def _finish(self, slot_state: _Slot, t_now: float):
@@ -1827,30 +1832,31 @@ class Server:
         else:
             step_toks = jnp.asarray(step)[:, None]  # (B, 1)
         t0 = time.perf_counter()
-        if self._paged:
-            logits, st.caches, skip = self._decode(
-                self.params, step_toks, st.caches, jnp.asarray(active),
-                jnp.asarray(st.tables),
-            )
-        else:
-            logits, st.caches, skip = self._decode(
-                self.params, step_toks, st.caches, jnp.asarray(active)
+        with device_program("decode"):
+            if self._paged:
+                logits, st.caches, skip = self._decode(
+                    self.params, step_toks, st.caches, jnp.asarray(active),
+                    jnp.asarray(st.tables),
+                )
+            else:
+                logits, st.caches, skip = self._decode(
+                    self.params, step_toks, st.caches, jnp.asarray(active)
+                )
+            skip = np.asarray(skip, np.float64)
+            last = np.asarray(
+                logits[:, -1] if cfg.frontend != "codes" else logits[:, 0],
+                np.float32,
             )
         self.metrics.decode_s += time.perf_counter() - t0
         self.metrics.ticks += 1
         self.metrics.decode_tokens += n_active
         self._vt += 1.0
         self._vt_decode += 1.0
-        skip = np.asarray(skip, np.float64)
         self.metrics.skipped_tile_dots += float(skip[0])
         self.metrics.total_tile_dots += float(skip[1])
         self._ema.update(float(skip[0]), float(skip[1]))
         self._maybe_replan()
 
-        last = np.asarray(
-            logits[:, -1] if cfg.frontend != "codes" else logits[:, 0],
-            np.float32,
-        )
         nxt = self._sample(last)  # (B,) or (B, K)
 
         # Per-slot bookkeeping + immediate release on EOS/budget.

@@ -89,7 +89,6 @@ def test_compressed_psum_approximates_mean():
     _run("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.launch import mesh as mesh_lib
 from repro.optim.compress import compressed_psum, init_residuals
 
@@ -101,9 +100,9 @@ def spmd(g, r):
     avg, new_r = compressed_psum({'w': g[0]}, {'w': r[0]}, 'data', method='int8')
     return avg['w'][None], new_r['w'][None]
 
-avg, new_r = shard_map(
+avg, new_r = jax.shard_map(
     spmd, mesh=mesh, in_specs=(P('data'), P('data')),
-    out_specs=(P('data'), P('data')), check_rep=False)(g, res)
+    out_specs=(P('data'), P('data')), check_vma=False)(g, res)
 true_mean = jnp.mean(g, axis=0)
 # all shards agree and approximate the true mean (int8 quantization)
 np.testing.assert_allclose(np.asarray(avg[0]), np.asarray(avg[7]), atol=1e-7)

@@ -25,9 +25,11 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import model as model_lib
+from repro.core.sparse_ops import SparsityConfig
+from repro.kernels import ops as kops
 from repro.runtime.errors import (
-    EngineFatal, RequestCancelled, RequestFailed, ServerOverloaded,
-    is_request_scoped,
+    DeviceProgramError, EngineFatal, RequestCancelled, RequestFailed,
+    ServerOverloaded, is_request_scoped,
 )
 from repro.runtime.faults import (
     FaultInjector, FaultPlan, InjectedFault, poison_uids_for,
@@ -141,6 +143,8 @@ def test_is_request_scoped_classification():
     assert is_request_scoped(ValueError("bad request shape"))
     assert is_request_scoped(FloatingPointError("nan"))
     assert not is_request_scoped(RuntimeError("pool corrupt"))
+    assert not is_request_scoped(jax.errors.JaxRuntimeError("device lost"))
+    assert not is_request_scoped(DeviceProgramError("decode program"))
     assert not is_request_scoped(EngineFatal("dead"))
     assert not is_request_scoped(KeyboardInterrupt())
 
@@ -237,6 +241,24 @@ def test_non_request_scoped_error_is_engine_fatal(model):
     with pytest.raises(RuntimeError, match="shut down"):
         asrv.submit(np.array([3]), max_new=1)
     asrv.shutdown(drain=False, timeout=60)
+
+
+def test_kernel_lowering_error_fails_the_run(model, monkeypatch):
+    """A kernel the compiler refuses is engine-fatal, not one failed
+    request. On the CPU backend a compiled Pallas kernel is refused at
+    lowering with a ValueError -- the type the chip's compiler raises
+    for a block it cannot tile -- which a request-scoped classifier
+    would read as a malformed prompt and keep serving."""
+    cfg, params = model
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
+    srv = Server(cfg, params, ServeConfig(
+        batch_slots=2, max_len=32, kv_block_size=4, attn_kernel="paged",
+        sparsity=SparsityConfig(enabled=True, mode="kernel", block_m=1)))
+    with pytest.raises(DeviceProgramError, match="prefill") as ei:
+        srv.generate(_requests(cfg, 0))
+    assert isinstance(ei.value.__cause__, ValueError)
+    assert not is_request_scoped(ei.value)
+    assert not srv.failed
 
 
 # ------------------------------------------------------------- the hammer

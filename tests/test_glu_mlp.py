@@ -35,8 +35,7 @@ def test_glu_fused_matches_oracle(act, tau):
     x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
     w_gate, w_in, w_out = _weights(jax.random.PRNGKey(1), K, F, N)
     y, bmp = kops.sparce_glu_mlp_fused(
-        x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act=act, tau=tau,
-        interpret=True)
+        x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act=act, tau=tau)
     want, bits = kref.glu_mlp_ref(
         x, w_gate, w_in, w_out, act=act, tau=tau, block_m=bm, block_f=bf)
     np.testing.assert_array_equal(np.asarray(bmp.bits), np.asarray(bits))
@@ -53,7 +52,7 @@ def test_glu_tau0_exact_vs_dense():
     w_gate, w_in, w_out = _weights(jax.random.PRNGKey(3), K, F, N)
     y, bmp = kops.sparce_glu_mlp_fused(
         x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act="silu",
-        tau=0.0, interpret=True)
+        tau=0.0)
     bits = np.asarray(bmp.bits)
     assert (bits[1] == 1).all()  # the zero row-tile is dead across F
     assert (bits[0] == 0).all() and (bits[2] == 0).all()
@@ -74,7 +73,7 @@ def test_glu_relu_gate_degenerates_to_exact_zero_test():
     x = jnp.abs(x)
     y, bmp = kops.sparce_glu_mlp_fused(
         x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act="relu",
-        tau=0.0, interpret=True)
+        tau=0.0)
     _, want_bits = kref.relu_bitmap_ref(jnp.dot(x, w_gate), (bm, bf))
     np.testing.assert_array_equal(np.asarray(bmp.bits),
                                   np.asarray(want_bits))
@@ -98,13 +97,13 @@ def test_glu_dead_stripes_skip_both_fetches():
     tau = 0.05
     y0, bmp = kops.sparce_glu_mlp_fused(
         x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act="silu",
-        tau=tau, interpret=True)
+        tau=tau)
     assert (np.asarray(bmp.bits)[:, 1] == 1).all()
     w_in_p = w_in.at[:, 128:].set(jnp.nan)
     w_out_p = w_out.at[128:, :].set(jnp.nan)
     y1, bmp1 = kops.sparce_glu_mlp_fused(
         x, w_gate, w_in_p, w_out_p, block_m=bm, block_f=bf, act="silu",
-        tau=tau, interpret=True)
+        tau=tau)
     np.testing.assert_array_equal(np.asarray(bmp.bits),
                                   np.asarray(bmp1.bits))
     np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
@@ -123,7 +122,7 @@ def test_glu_fused_bf16_bits_exact_values_close():
         jax.random.PRNGKey(9), K, F, N, dtype=jnp.bfloat16)
     y, bmp = kops.sparce_glu_mlp_fused(
         x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act="silu",
-        tau=0.0, interpret=True)
+        tau=0.0)
     want, bits = kref.glu_mlp_ref(
         x, w_gate, w_in, w_out, act="silu", tau=0.0, block_m=bm,
         block_f=bf)
@@ -142,7 +141,7 @@ def test_glu_fused_ragged_dims_padded():
     w_gate, w_in, w_out = _weights(jax.random.PRNGKey(11), K, F, N)
     y, bmp = kops.sparce_glu_mlp_fused(
         x, w_gate, w_in, w_out, block_m=bm, block_f=bf, act="gelu",
-        tau=0.02, interpret=True)
+        tau=0.02)
     assert y.shape == (M, N)
     want, bits = kref.glu_mlp_ref(
         x, w_gate, w_in, w_out, act="gelu", tau=0.02, block_m=bm,

@@ -132,7 +132,7 @@ def test_ops_wrapper_pads_ragged_dims():
     x = sprf.random_sparse(jax.random.PRNGKey(8), (M, K), 0.6, cluster=(50, 100))
     w = jax.random.normal(jax.random.PRNGKey(9), (K, N))
     bmp = sprf.compute_bitmap(x, (64, 128))
-    got = kops.sparce_gemm(x, w, plan, lhs_bitmap=bmp, interpret=True)
+    got = kops.sparce_gemm(x, w, plan, lhs_bitmap=bmp)
     want = jnp.dot(x, w)  # honest bitmap => exact dense semantics
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **F32_TOL)
     assert got.shape == (M, N)

@@ -217,10 +217,13 @@ def test_second_request_waits_for_free_blocks_not_free_slot():
 
 
 # ------------------------------------------------------- bucketed prefill
-def test_masked_prefill_bitwise_matches_exact_length():
-    """Padded-to-bucket prefill with advance/last-real-logit gather is
-    BIT-FOR-BIT the exact-length prefill: same last-position logits, same
-    cache lengths."""
+def test_masked_prefill_matches_exact_length_within_f32_rounding():
+    """Padded-to-bucket prefill with advance/last-real-logit gather
+    matches the exact-length prefill: same cache lengths, and the same
+    last-position logits up to float32 rounding. The padded run reduces
+    over 16 positions where the exact one reduces over 5, so XLA may
+    order the sums differently (2.7e-7 apart on its CPU backend); the
+    tolerance is a few float32 ulps of logits of magnitude ~1."""
     import jax.numpy as jnp
     for arch in ("smollm-135m", "musicgen-large"):
         cfg, params = _setup(arch)
@@ -243,7 +246,8 @@ def test_masked_prefill_bitwise_matches_exact_length():
             {"tokens": jnp.asarray(padded),
              "advance": jnp.asarray([S], jnp.int32)},
             model_lib.init_caches(cfg, 1, pad_to), last_only=True)
-        np.testing.assert_array_equal(np.asarray(lg_e), np.asarray(lg_b))
+        np.testing.assert_allclose(
+            np.asarray(lg_e), np.asarray(lg_b), rtol=1e-5, atol=1e-5)
         assert int(c_e["stack"].length[0][0]) == S
         assert int(c_b["stack"].length[0][0]) == S
 

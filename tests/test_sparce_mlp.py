@@ -52,7 +52,7 @@ def test_fused_mlp_matches_oracle(sparsity, act):
     w_in = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (K, F))) * 0.1
     w_out = jax.random.normal(jax.random.PRNGKey(2), (F, N)) * 0.1
     y, bmp = kops.sparce_mlp_fused(
-        x, w_in, w_out, block_m=bm, block_f=bf, act=act, interpret=True)
+        x, w_in, w_out, block_m=bm, block_f=bf, act=act)
     want, bits = _mlp_oracle(x, w_in, w_out, (bm, bf), act=act)
     np.testing.assert_array_equal(np.asarray(bmp.bits), np.asarray(bits))
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), **F32_TOL)
@@ -67,7 +67,7 @@ def test_fused_mlp_bf16_tolerance():
     w_out = (jax.random.normal(jax.random.PRNGKey(5), (F, N)) * 0.1).astype(
         jnp.bfloat16)
     y, bmp = kops.sparce_mlp_fused(
-        x, w_in, w_out, block_m=bm, block_f=bf, interpret=True)
+        x, w_in, w_out, block_m=bm, block_f=bf)
     want, bits = _mlp_oracle(x, w_in, w_out, (bm, bf))
     np.testing.assert_array_equal(np.asarray(bmp.bits), np.asarray(bits))
     np.testing.assert_allclose(
@@ -84,7 +84,7 @@ def test_fused_mlp_odd_patterns():
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(8), (M, K)))
     x = x.at[16:32].set(0.0)
     y, bmp = kops.sparce_mlp_fused(
-        x, w_in, w_out, block_m=bm, block_f=bf, interpret=True)
+        x, w_in, w_out, block_m=bm, block_f=bf)
     want, bits = _mlp_oracle(x, w_in, w_out, (bm, bf))
     np.testing.assert_array_equal(np.asarray(bmp.bits), np.asarray(bits))
     assert float(jnp.abs(y[16:32]).max()) == 0.0
@@ -93,7 +93,7 @@ def test_fused_mlp_odd_patterns():
     # fully dense: no bit set, still numerically correct
     xd = jnp.abs(jax.random.normal(jax.random.PRNGKey(9), (M, K))) + 0.1
     y, bmp = kops.sparce_mlp_fused(
-        xd, w_in, w_out, block_m=bm, block_f=bf, interpret=True)
+        xd, w_in, w_out, block_m=bm, block_f=bf)
     assert int(np.asarray(bmp.bits).sum()) == 0
     want, _ = _mlp_oracle(xd, w_in, w_out, (bm, bf))
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), **F32_TOL)
@@ -101,7 +101,7 @@ def test_fused_mlp_odd_patterns():
     # single nonzero element: exactly one live row-tile of bits
     xs = jnp.zeros((M, K)).at[3, 5].set(2.0)
     y, bmp = kops.sparce_mlp_fused(
-        xs, w_in, w_out, block_m=bm, block_f=bf, interpret=True)
+        xs, w_in, w_out, block_m=bm, block_f=bf)
     bits = np.asarray(bmp.bits)
     assert (bits[1:] == 1).all() and (bits[0] == 0).any()
     want, _ = _mlp_oracle(xs, w_in, w_out, (bm, bf))
@@ -115,7 +115,7 @@ def test_fused_mlp_ragged_dims_padded():
     w_in = jnp.abs(jax.random.normal(jax.random.PRNGKey(11), (K, F))) * 0.1
     w_out = jax.random.normal(jax.random.PRNGKey(12), (F, N)) * 0.1
     y, bmp = kops.sparce_mlp_fused(
-        x, w_in, w_out, block_m=bm, block_f=bf, interpret=True)
+        x, w_in, w_out, block_m=bm, block_f=bf)
     assert y.shape == (M, N)
     want, bits = _mlp_oracle(x, w_in, w_out, (bm, bf))
     np.testing.assert_array_equal(np.asarray(bmp.bits), np.asarray(bits))
@@ -132,11 +132,11 @@ def test_fused_skips_are_real():
     w_in = w_in.at[:, 128:256].set(-1.0)
     w_out = jax.random.normal(jax.random.PRNGKey(15), (F, N)) * 0.1
     y0, bmp = kops.sparce_mlp_fused(
-        x, w_in, w_out, block_m=bm, block_f=bf, interpret=True)
+        x, w_in, w_out, block_m=bm, block_f=bf)
     assert (np.asarray(bmp.bits)[:, 1] == 1).all()
     w_poison = w_out.at[128:256].set(jnp.nan)  # stripe must not be read
     y1, _ = kops.sparce_mlp_fused(
-        x, w_in, w_poison, block_m=bm, block_f=bf, interpret=True)
+        x, w_in, w_poison, block_m=bm, block_f=bf)
     np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
     assert not np.any(np.isnan(np.asarray(y1)))
 
@@ -311,10 +311,10 @@ def test_server_fused_mode_matches_reference_engine():
 
 def test_measuring_autotuner_returns_timed_plan():
     plan, timings = sasa.autotune_mlp_plan(
-        32, 64, 256, 64, measured_block_sparsity=0.5, interpret=True)
+        32, 64, 256, 64, measured_block_sparsity=0.5)
     assert plan.variant in ("fused", "two_kernel")
     assert set(timings) == {"fused", "two_kernel"}
     assert all(t > 0 for t in timings.values())
     again, _ = sasa.autotune_mlp_plan(
-        32, 64, 256, 64, measured_block_sparsity=0.5, interpret=True)
+        32, 64, 256, 64, measured_block_sparsity=0.5)
     assert again is plan  # memoised process-wide

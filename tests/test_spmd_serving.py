@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
@@ -240,11 +239,11 @@ def test_sharded_paged_attn_nan_poisoned_blocks_never_fetched(cpu_mesh):
     def body(q, kp, vp, tables, lengths):
         return kops.paged_decode_attn(q, kp, vp, tables, lengths)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("data", "model"), P("data", None, "model"),
                   P("data", None, "model"), P("data"), P("data")),
-        out_specs=P("data", "model"), check_rep=False))
+        out_specs=P("data", "model"), check_vma=False))
     args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths))
     base = np.asarray(fn(*args))
     # Oracle: the same case through the gather reference with GLOBAL ids.
@@ -292,15 +291,14 @@ def test_sharded_glu_mlp_dead_stripes_skip_both_fetches(cpu_mesh):
 
     def body(x, wg, wi, wo):
         y, bmp = kops.sparce_glu_mlp_fused(
-            x, wg, wi, wo, block_m=bm, block_f=bf, act="silu", tau=tau,
-            interpret=True)
+            x, wg, wi, wo, block_m=bm, block_f=bf, act="silu", tau=tau)
         return jax.lax.psum(y, "model"), bmp.bits
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, "model"), P(None, "model"),
                   P("model", None)),
-        out_specs=(P(), P(None, "model")), check_rep=False))
+        out_specs=(P(), P(None, "model")), check_vma=False))
     y0, bits0 = fn(x, w_gate, w_in, w_out)
     bits0 = np.asarray(bits0)
     # Local stripe 1 of each shard (global stripe 1 and 3) is dead.
