@@ -6,8 +6,7 @@ the perf trajectory is tracked across PRs; CI's bench-smoke job pins the
 deterministic modeled-HBM-bytes fields against a committed baseline.
 
 Usage:
-    PYTHONPATH=src python -m benchmarks.run [--only fig17] \
-        [--skip-roofline] [--json-dir .]
+    PYTHONPATH=src python -m benchmarks.run [--only fig17] [--json-dir .]
 """
 from __future__ import annotations
 
@@ -21,8 +20,6 @@ import traceback
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
-    ap.add_argument("--skip-roofline", action="store_true")
-    ap.add_argument("--roofline-dir", default="results/dryrun_final")
     ap.add_argument("--json-dir", default=".",
                     help="where BENCH_*.json artifacts are written")
     args = ap.parse_args()
@@ -30,7 +27,7 @@ def main() -> None:
     from benchmarks import (
         fig4_redundant_ops, fig14_app_time, fig16_layerwise,
         fig17_sparsity_scaling, fig18_operand_order, fused_mlp,
-        moe_structural, roofline_report, serve_cache_skip,
+        moe_structural, serve_cache_skip,
     )
 
     os.makedirs(args.json_dir, exist_ok=True)
@@ -54,13 +51,6 @@ def main() -> None:
          functools.partial(serve_cache_skip.run_spmd,
                            json_path=jp("BENCH_spmd.json"))),
     ]
-    if not args.skip_roofline:
-        rdir = args.roofline_dir
-        if not os.path.isdir(rdir):
-            rdir = "results/dryrun"
-        suites.append(
-            ("roofline", functools.partial(roofline_report.run, rdir)))
-
     print("name,us_per_call,derived")
     failed = 0
     for name, fn in suites:

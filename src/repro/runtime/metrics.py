@@ -14,11 +14,20 @@ Units: token/tick/block counters are counts; ``*_s`` fields are wall
 seconds; ``*_ticks_*`` fields are virtual decode-tick units (the
 deterministic clock CI gates run on); ``*_bytes*`` fields are modeled
 HBM bytes from ``core/cost_model.py``; fractions are in [0, 1].
+
+:func:`span` is the engine's one tracing hook: each engine boundary adds
+its host time to a ``*_s`` field here and, while a ``jax.profiler``
+trace runs, records a ``serve.*`` host event on the device trace's
+clock.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Dict, Optional
+import time
+from typing import Dict, Iterator, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -39,6 +48,25 @@ class ServeMetrics:
     prefill_s: float = 0.0  # wall seconds in prefill calls
     decode_s: float = 0.0  # wall seconds in decode ticks
     replans: float = 0.0  # SASA autotune re-jits
+
+    # --- engine host time (wall seconds; see span) -----------------------
+    # step_s holds admit_s, decode_prep_s, decode_s, sample_s and emit_s;
+    # admit_s holds prefill_s; decode_s holds decode_wait_s and
+    # readback_s.
+    step_s: float = 0.0  # Server.step calls
+    admit_s: float = 0.0  # admission phases (prefills included)
+    decode_prep_s: float = 0.0  # tick set-up before the decode call
+    decode_wait_s: float = 0.0  # waiting for the decode program's outputs
+    readback_s: float = 0.0  # copying the decode's logits to the host
+    sample_s: float = 0.0  # sampling the next tokens on the host
+    emit_s: float = 0.0  # per-slot bookkeeping, token callbacks, releases
+    # Summed over admissions: admission time minus the request's arrival
+    # (a restored preempted request counts from its first arrival).
+    queue_wait_s: float = 0.0
+    # Summed over decode ticks: KV rows held by the live slots, and the
+    # paged pool's rows (kv_pool_blocks x kv_block_size; 0 unpaged).
+    kv_live_row_ticks: float = 0.0
+    kv_pool_row_ticks: float = 0.0
 
     # --- SparCE skip accounting ------------------------------------------
     skipped_tile_dots: float = 0.0  # MLP tile-dots skipped (all phases)
@@ -162,3 +190,17 @@ class ServeMetrics:
 
     def __contains__(self, key: str) -> bool:
         return hasattr(self, key)
+
+
+@contextlib.contextmanager
+def span(metrics: ServeMetrics, field: str, name: str,
+         **args) -> Iterator[None]:
+    """Time one engine boundary: add its ``time.perf_counter()`` length
+    to ``metrics.<field>`` and, while a profiler trace runs, record it as
+    the host event ``name`` (with ``args`` as its stats). An exception
+    passes through and adds nothing, like the counters' own bounds."""
+    t0 = time.perf_counter()
+    with TraceAnnotation(name, **args):
+        yield
+    setattr(metrics, field,
+            getattr(metrics, field) + time.perf_counter() - t0)

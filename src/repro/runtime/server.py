@@ -106,7 +106,7 @@ from repro.runtime.errors import (
     ServingError, device_program, is_request_scoped,
 )
 from repro.runtime.faults import FaultInjector, FaultPlan, InjectedFault
-from repro.runtime.metrics import ServeMetrics
+from repro.runtime.metrics import ServeMetrics, span
 from repro.runtime.paging import (
     BlockAllocator, PrefixCache, blocks_needed, pick_bucket,
     resolve_buckets,
@@ -605,26 +605,28 @@ class Server:
         if self._mesh is not None:
             self._build_mesh_step_fns(key)
             return
+        # The step programs are named functions, so a profiler trace
+        # names them jit_serve_decode / jit_serve_prefill /
+        # jit_serve_prefill_cached (and PjitFunction(serve_...) on the
+        # host), on one host and on a mesh alike.
         if self._paged:
             attn_kernel = serve_cfg.attn_kernel
-            self._decode = jax.jit(
-                lambda p, toks, caches, active, tables:
-                model_lib.serving_decode_step(
+
+            def serve_decode(p, toks, caches, active, tables):
+                return model_lib.serving_decode_step(
                     p, cfg, toks, caches, active, tables,
                     attn_kernel=attn_kernel,
                 )
-            )
         else:
-            self._decode = jax.jit(
-                lambda p, toks, caches, active:
-                model_lib.serving_decode_step(
+            def serve_decode(p, toks, caches, active):
+                return model_lib.serving_decode_step(
                     p, cfg, toks, caches, active
                 )
-            )
+        self._decode = jax.jit(serve_decode)
         paged = self._paged
         patch_rows = self._patch_rows
 
-        def _prefill_fn(p, batch):
+        def serve_prefill(p, batch):
             # Paged mode sizes the scratch cache at the (bucketed) prompt
             # itself -- the rows are immediately re-scattered into pool
             # blocks, so no max_len reservation ever exists. Contiguous
@@ -639,12 +641,13 @@ class Server:
             # skip metrics too, not just decode ticks.
             return logits, new_caches, aux["skip"]
 
-        self._prefill = jax.jit(_prefill_fn)
+        self._prefill = jax.jit(serve_prefill)
 
         if paged and serve_cfg.prefix_cache:
             ext_rows = self._ext_rows
 
-            def _prefill_cached_fn(p, batch, pool, block_ids, prefix_len):
+            def serve_prefill_cached(p, batch, pool, block_ids,
+                                     prefix_len):
                 # Suffix-only continuation prefill: gather the matched
                 # prefix rows out of the POOL into a batch=1 scratch
                 # cache pinned at length=prefix_len, then run only the
@@ -661,7 +664,7 @@ class Server:
                 )
                 return logits, new_caches, aux["skip"]
 
-            self._prefill_cached = jax.jit(_prefill_cached_fn)
+            self._prefill_cached = jax.jit(serve_prefill_cached)
         else:
             self._prefill_cached = None
         self._step_fn_cache[key] = (
@@ -697,7 +700,8 @@ class Server:
         attn_kernel = serve_cfg.attn_kernel
         patch_rows = self._patch_rows
 
-        def _decode_body(p, toks, caches, active, tables):
+        # shard_map keeps the body's name: jit_serve_decode and so on.
+        def serve_decode(p, toks, caches, active, tables):
             logits, new_caches, skip = model_lib.serving_decode_step(
                 p, cfg_l, toks, caches, active, tables,
                 attn_kernel=attn_kernel,
@@ -708,14 +712,14 @@ class Server:
                 skip, ("data", "model"))
 
         self._decode = jax.jit(jax.shard_map(
-            _decode_body, mesh=mesh,
+            serve_decode, mesh=mesh,
             in_specs=(pspecs, P("data"), pool_specs, P("data"),
                       P("data")),
             out_specs=(P("data"), pool_specs, P()),
             check_vma=False,
         ))
 
-        def _prefill_body(p, batch):
+        def serve_prefill(p, batch):
             # Prefill is batch=1 and replicated over 'data' (every data
             # shard runs the same prompt); only the heads/FFN split over
             # 'model'. The scratch cache is built INSIDE the body at the
@@ -731,7 +735,7 @@ class Server:
                 aux["skip"], ("model",))
 
         self._prefill = jax.jit(jax.shard_map(
-            _prefill_body, mesh=mesh,
+            serve_prefill, mesh=mesh,
             in_specs=(pspecs, P()),
             out_specs=(P(), small_specs, P()),
             check_vma=False,
@@ -755,8 +759,8 @@ class Server:
                 check_vma=False,
             )
 
-            def _prefill_cached_fn(p, batch, pool, block_ids,
-                                   prefix_len):
+            def serve_prefill_cached(p, batch, pool, block_ids,
+                                     prefix_len):
                 # The prefix gather runs GSPMD over the sharded pool
                 # with GLOBAL block ids; the resulting small cache is
                 # resharded to per-shard KV width by the shard_map
@@ -765,7 +769,7 @@ class Server:
                     pool, block_ids, prefix_len, ext_rows)
                 return suffix_sm(p, batch, small)
 
-            self._prefill_cached = jax.jit(_prefill_cached_fn)
+            self._prefill_cached = jax.jit(serve_prefill_cached)
         else:
             self._prefill_cached = None
         self._step_fn_cache[key] = (
@@ -864,24 +868,26 @@ class Server:
             # stays shard-LOCAL. Padding zeros shift to the owner's
             # null block (base + 0), whose content is never read.
             ids += self._slot_base(slot)
-        t0 = time.perf_counter()
-        with device_program("prefill"):
-            logits, small, skip = self._prefill(self.params, batch)
-            if ids is not None:
-                caches = model_lib.insert_slot_paged(
-                    caches, small, jnp.int32(slot), jnp.asarray(ids),
-                    jnp.int32(rows0),
-                )
-            else:
-                caches = model_lib.insert_slot_caches(caches, small, slot)
-            # last_only logits: (1, 1, V) or (1, 1, K, V) for codes.
-            last = np.asarray(logits[0, 0], np.float32)  # (V,) or (K, V)
-            skip = np.asarray(skip, np.float64)
-        # Host-side trace ledger: one entry per (jitted fn, shape), so it
-        # counts replan retraces too and stays a faithful fallback if the
-        # jit-cache probe (_cache_size, a private JAX API) ever goes away.
-        self._prefill_shapes.add((id(self._prefill), cfg.frontend, S_pad))
-        self.metrics.prefill_s += time.perf_counter() - t0
+        with span(self.metrics, "prefill_s", "serve.prefill", uid=r.uid):
+            with device_program("prefill"):
+                logits, small, skip = self._prefill(self.params, batch)
+                if ids is not None:
+                    caches = model_lib.insert_slot_paged(
+                        caches, small, jnp.int32(slot), jnp.asarray(ids),
+                        jnp.int32(rows0),
+                    )
+                else:
+                    caches = model_lib.insert_slot_caches(
+                        caches, small, slot)
+                # last_only logits: (1, 1, V) or (1, 1, K, V) for codes.
+                last = np.asarray(logits[0, 0], np.float32)  # (V,)/(K, V)
+                skip = np.asarray(skip, np.float64)
+            # Host-side trace ledger: one entry per (jitted fn, shape), so
+            # it counts replan retraces too and stays a faithful fallback
+            # if the jit-cache probe (_cache_size, a private JAX API) ever
+            # goes away.
+            self._prefill_shapes.add(
+                (id(self._prefill), cfg.frontend, S_pad))
         self.metrics.prefill_tokens += S
         self.metrics.admitted += 1
         self._count_prefill_skip(skip)
@@ -929,25 +935,25 @@ class Server:
             "tokens": jnp.asarray(toks),
             "advance": jnp.asarray([n_suffix], jnp.int32),
         }
-        t0 = time.perf_counter()
-        # The pool gather and suffix scatter are GSPMD ops over the whole
-        # sharded pool: feed them GLOBAL flat ids (owner base + local).
-        g_row = (np.asarray(table_row, np.int32)
-                 + np.int32(self._slot_base(slot)))
-        with device_program("suffix prefill"):
-            logits, small, skip = self._prefill_cached(
-                self.params, batch, caches, jnp.asarray(g_row),
-                jnp.int32(prefix_len),
-            )
-            caches = model_lib.insert_slot_paged_from(
-                caches, small, jnp.int32(slot), jnp.asarray(g_row),
-                jnp.int32(rows0), jnp.int32(prefix_len),
-            )
-            last = np.asarray(logits[0, 0], np.float32)  # (V,) or (K, V)
-            skip = np.asarray(skip, np.float64)
-        self._prefill_shapes.add(
-            (id(self._prefill_cached), cfg.frontend, S_pad))
-        self.metrics.prefill_s += time.perf_counter() - t0
+        with span(self.metrics, "prefill_s", "serve.prefill", uid=r.uid):
+            # The pool gather and suffix scatter are GSPMD ops over the
+            # whole sharded pool: feed them GLOBAL flat ids (owner base +
+            # local).
+            g_row = (np.asarray(table_row, np.int32)
+                     + np.int32(self._slot_base(slot)))
+            with device_program("suffix prefill"):
+                logits, small, skip = self._prefill_cached(
+                    self.params, batch, caches, jnp.asarray(g_row),
+                    jnp.int32(prefix_len),
+                )
+                caches = model_lib.insert_slot_paged_from(
+                    caches, small, jnp.int32(slot), jnp.asarray(g_row),
+                    jnp.int32(rows0), jnp.int32(prefix_len),
+                )
+                last = np.asarray(logits[0, 0], np.float32)  # (V,)/(K, V)
+                skip = np.asarray(skip, np.float64)
+            self._prefill_shapes.add(
+                (id(self._prefill_cached), cfg.frontend, S_pad))
         # Only the suffix actually prefilled; the matched rows are the
         # prefix_matched_tokens counter's business.
         self.metrics.prefill_tokens += n_suffix
@@ -1669,6 +1675,7 @@ class Server:
             if isinstance(e, InjectedFault):
                 self.metrics.faults_recovered += 1
             return "retry"
+        self.metrics.queue_wait_s += t0 - item.arrival_s
         self._vt += pt
         self._vt_prefill += pt
         if resumed:
@@ -1731,8 +1738,54 @@ class Server:
     def _decode_tick(self) -> int:
         """One fused decode tick for all slots (dead slots masked).
         Returns the number of live slots it decoded for (0 = no-op)."""
+        st, cfg, m = self._st, self.cfg, self.metrics
+        with span(m, "decode_prep_s", "serve.decode.prep"):
+            prep = self._prepare_tick()
+        if prep is None:
+            return 0
+        active, n_active, step_toks = prep
+        with span(m, "decode_s", "serve.decode"):
+            with device_program("decode"):
+                if self._paged:
+                    logits, st.caches, skip = self._decode(
+                        self.params, step_toks, st.caches,
+                        jnp.asarray(active), jnp.asarray(st.tables),
+                    )
+                else:
+                    logits, st.caches, skip = self._decode(
+                        self.params, step_toks, st.caches,
+                        jnp.asarray(active)
+                    )
+                with span(m, "decode_wait_s", "serve.decode.wait"):
+                    jax.block_until_ready((logits, st.caches, skip))
+                with span(m, "readback_s", "serve.decode.readback"):
+                    skip = np.asarray(skip, np.float64)
+                    last = np.asarray(
+                        logits[:, -1] if cfg.frontend != "codes"
+                        else logits[:, 0],
+                        np.float32,
+                    )
+        m.ticks += 1
+        m.decode_tokens += n_active
+        self._vt += 1.0
+        self._vt_decode += 1.0
+        m.skipped_tile_dots += float(skip[0])
+        m.total_tile_dots += float(skip[1])
+        self._ema.update(float(skip[0]), float(skip[1]))
+        self._maybe_replan()
+
+        with span(m, "sample_s", "serve.sample"):
+            nxt = self._sample(last)  # (B,) or (B, K)
+        with span(m, "emit_s", "serve.emit"):
+            self._emit_tick(nxt)
+        return n_active
+
+    def _prepare_tick(self):
+        """The decode tick's host set-up: injected step faults, the
+        active mask, lazy block growth, pool and attention-fetch
+        accounting, the step tokens. Returns ``(active, n_active,
+        step_toks)``, or None when no slot is live."""
         st, cfg, sc = self._st, self.cfg, self.sc
-        B = sc.batch_slots
         if self._inj is not None:
             # Request-scoped step faults: one slot's host-side step
             # handling blows up; that slot is evicted (resources back,
@@ -1753,8 +1806,7 @@ class Server:
         )
         n_active = int(active.sum())
         if n_active == 0:
-            return 0
-        slo = self.sc.slo
+            return None
         if self._paged:
             # Lazy growth: a slot crossing a block edge claims its
             # next pool block only when the write reaches it. The
@@ -1781,7 +1833,7 @@ class Server:
                 [s is not None for s in st.slots], np.float32)
             n_active = int(active.sum())
             if n_active == 0:
-                return 0
+                return None
             self.metrics.kv_blocks_peak_in_use = max(
                 self.metrics.kv_blocks_peak_in_use,
                 float(sum(a.in_use for a in st.allocs)))
@@ -1821,6 +1873,10 @@ class Server:
                 eff, self._max_blocks, sc.kv_block_size)
             self._attn_fetched += fetched
             self._attn_total += total
+        self.metrics.kv_live_row_ticks += sum(
+            s.cache_len for s in st.slots if s is not None)
+        self.metrics.kv_pool_row_ticks += (
+            self.metrics.kv_pool_blocks * self.metrics.kv_block_size)
         cur_tok = st.cur_tok
         step = np.where(
             active.astype(bool)[:, None] if cur_tok.ndim > 1
@@ -1831,36 +1887,14 @@ class Server:
             step_toks = jnp.asarray(step)[..., None]  # (B, K, 1)
         else:
             step_toks = jnp.asarray(step)[:, None]  # (B, 1)
-        t0 = time.perf_counter()
-        with device_program("decode"):
-            if self._paged:
-                logits, st.caches, skip = self._decode(
-                    self.params, step_toks, st.caches, jnp.asarray(active),
-                    jnp.asarray(st.tables),
-                )
-            else:
-                logits, st.caches, skip = self._decode(
-                    self.params, step_toks, st.caches, jnp.asarray(active)
-                )
-            skip = np.asarray(skip, np.float64)
-            last = np.asarray(
-                logits[:, -1] if cfg.frontend != "codes" else logits[:, 0],
-                np.float32,
-            )
-        self.metrics.decode_s += time.perf_counter() - t0
-        self.metrics.ticks += 1
-        self.metrics.decode_tokens += n_active
-        self._vt += 1.0
-        self._vt_decode += 1.0
-        self.metrics.skipped_tile_dots += float(skip[0])
-        self.metrics.total_tile_dots += float(skip[1])
-        self._ema.update(float(skip[0]), float(skip[1]))
-        self._maybe_replan()
+        return active, n_active, step_toks
 
-        nxt = self._sample(last)  # (B,) or (B, K)
-
-        # Per-slot bookkeeping + immediate release on EOS/budget.
-        for i in range(B):
+    def _emit_tick(self, nxt: np.ndarray) -> None:
+        """Per-slot bookkeeping of a decode tick's tokens ``nxt``: token
+        callbacks, ITL stats, and immediate release on EOS/budget."""
+        st, slo = self._st, self.sc.slo
+        cur_tok = st.cur_tok
+        for i in range(self.sc.batch_slots):
             s = st.slots[i]
             if s is None:
                 continue
@@ -1890,7 +1924,6 @@ class Server:
                 self._fail_slot(i, RequestCancelled(
                     f"request uid={s.req.uid}: max_ticks="
                     f"{s.req.max_ticks} decode budget exhausted"))
-        return n_active
 
     def step(self) -> Dict[str, int]:
         """One engine iteration: an admission phase, then (if anything
@@ -1898,9 +1931,11 @@ class Server:
         ``queue_depth() or any_active()``."""
         if self._st is None:
             raise RuntimeError("start_engine() before step()")
-        self._process_cancellations()
-        admitted = self._admission_phase()
-        decoded = self._decode_tick()
+        with span(self.metrics, "step_s", "serve.step"):
+            self._process_cancellations()
+            with span(self.metrics, "admit_s", "serve.admit"):
+                admitted = self._admission_phase()
+            decoded = self._decode_tick()
         return {"admitted": admitted, "decoded": decoded}
 
     def serve_trace(self, trace, *, priorities=None,

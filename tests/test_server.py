@@ -239,3 +239,81 @@ def test_per_request_stats_populated():
     s = done[0].stats
     assert s["tokens"] == 4 and s["decode_ticks"] == 3
     assert s["latency_s"] >= s["ttft_s"] >= 0
+
+
+# ---------------------------------------------- engine spans and counters
+def _served(n_requests=5, seed=4):
+    """A finished run of seeded traffic (no EOS) through two slots."""
+    cfg, params = _setup("smollm-135m")
+    reqs = make_traffic(cfg, Traffic(n_requests=n_requests,
+                                     prompt_lens=(3, 9), max_new=(1, 6),
+                                     seed=seed))
+    srv = Server(cfg, params, ServeConfig(batch_slots=2, max_len=32))
+    srv.generate(reqs)
+    return srv, reqs
+
+
+def test_engine_host_counters_nest_and_count_live_rows():
+    srv, reqs = _served()
+    m = srv.metrics
+    tol = 1e-6  # float sums of nested perf_counter intervals
+    assert m.step_s > 0 and m.decode_wait_s > 0 and m.readback_s > 0
+    assert m.step_s + tol >= (m.admit_s + m.decode_prep_s + m.decode_s
+                              + m.sample_s + m.emit_s)
+    assert m.decode_s + tol >= m.decode_wait_s + m.readback_s
+    assert m.admit_s + tol >= m.prefill_s > 0
+    assert m.queue_wait_s >= 0
+    # A request with a p-token prompt and n tokens is live in n - 1
+    # decode ticks, holding p, p + 1, ..., p + n - 2 rows in them.
+    rows = 0
+    for r in reqs:
+        p, n = len(r.prompt), r.max_new
+        rows += sum(p + j for j in range(n - 1))
+    assert m.kv_live_row_ticks == rows
+    assert m.ticks > 0
+    assert m.kv_pool_row_ticks == (
+        m.ticks * m.kv_pool_blocks * m.kv_block_size) > 0
+
+
+def test_step_programs_have_stable_names():
+    import re
+
+    srv, _ = _served(n_requests=1)
+    st, B = srv._st, srv.sc.batch_slots
+
+    def module(lowered):
+        return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+    assert module(srv._decode.lower(
+        srv.params, jnp.zeros((B, 1), jnp.int32), st.caches,
+        jnp.zeros((B,), jnp.float32), jnp.asarray(st.tables),
+    )) == "jit_serve_decode"
+    assert module(srv._prefill.lower(
+        srv.params, {"tokens": jnp.zeros((1, 8), jnp.int32),
+                     "advance": jnp.asarray([8], jnp.int32)},
+    )) == "jit_serve_prefill"
+
+
+def test_profiler_trace_holds_engine_spans(tmp_path):
+    import pathlib
+    import sys
+
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from bench import trace
+
+    cfg, params = _setup("smollm-135m")
+    reqs = make_traffic(cfg, Traffic(n_requests=2, prompt_lens=(4, 6),
+                                     max_new=(3, 3), seed=5))
+    srv = Server(cfg, params, ServeConfig(batch_slots=2, max_len=32))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        srv.generate(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    host = trace.load(trace.find_xplane(str(tmp_path)))["host"]
+    names = {n for n, _, _ in host}
+    assert {"serve.step", "serve.admit", "serve.prefill",
+            "serve.decode.prep", "serve.decode", "serve.decode.wait",
+            "serve.decode.readback", "serve.sample",
+            "serve.emit"} <= names
+    assert sum(n == "serve.prefill" for n, _, _ in host) == 2
