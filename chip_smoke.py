@@ -290,17 +290,26 @@ class _TapServer(Server):
     def __init__(self, *a, **kw):
         super().__init__(*a, **kw)
         self.prefill_logits, self.decode_logits = {}, {}
+        self._in_tick = False
 
     def _prefill_one(self, r, slot, caches, block_ids=None):
         last, caches = super()._prefill_one(r, slot, caches, block_ids)
-        self.prefill_logits[r.uid] = last
+        self.prefill_logits[r.uid] = np.asarray(last[0, 0], np.float32)
         return last, caches
 
+    def _decode_tick(self):
+        self._in_tick = True
+        try:
+            return super()._decode_tick()
+        finally:
+            self._in_tick = False
+
     def _sample(self, logits):
-        if logits.ndim == 2:  # a decode tick: (B, V)
+        if self._in_tick:  # a decode tick: (B, 1, V)
+            last = np.asarray(logits[:, 0], np.float32)
             for i, s in enumerate(self._st.slots):
                 if s is not None:
-                    self.decode_logits.setdefault(s.req.uid, logits[i])
+                    self.decode_logits.setdefault(s.req.uid, last[i])
         return super()._sample(logits)
 
 

@@ -48,6 +48,7 @@ class ServeMetrics:
     prefill_s: float = 0.0  # wall seconds in prefill calls
     decode_s: float = 0.0  # wall seconds in decode ticks
     replans: float = 0.0  # SASA autotune re-jits
+    device_sample_ticks: float = 0.0  # decode ticks sampled on the device
 
     # --- engine host time (wall seconds; see span) -----------------------
     # step_s holds admit_s, decode_prep_s, decode_s, sample_s and emit_s;
@@ -57,8 +58,10 @@ class ServeMetrics:
     admit_s: float = 0.0  # admission phases (prefills included)
     decode_prep_s: float = 0.0  # tick set-up before the decode call
     decode_wait_s: float = 0.0  # waiting for the decode program's outputs
-    readback_s: float = 0.0  # copying the decode's logits to the host
-    sample_s: float = 0.0  # sampling the next tokens on the host
+    readback_s: float = 0.0  # copying the decode's skip counts to the host
+    # Choosing the next tokens: greedy dispatches serve_sample and copies
+    # the ids; a temperature copies the logits and samples on the host.
+    sample_s: float = 0.0
     emit_s: float = 0.0  # per-slot bookkeeping, token callbacks, releases
     # Summed over admissions: admission time minus the request's arrival
     # (a restored preempted request counts from its first arrival).

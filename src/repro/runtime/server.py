@@ -73,8 +73,12 @@ lock), the allocator's atomic reservations, and reading ``metrics``
 values. ``AsyncServer`` serializes all engine work on its one background
 thread.
 
-Sampling is vectorized (Gumbel-max over the whole slot batch; greedy is
-pure argmax), so there is no per-row Python sampling loop. The server
+Sampling is vectorized over the whole slot batch, so there is no
+per-row Python sampling loop. Greedy sampling (``temperature <= 0``) is
+the step program ``serve_sample``: the argmax runs on the device over the
+step's logits and only the (slots,) int32 ids are copied to the host.
+``temperature > 0`` copies the logits and draws Gumbel-max on the host
+from the engine's seeded numpy stream. The server
 reports engine metrics (ticks, active-token counts, realized MLP
 tile-skip fraction, pool occupancy/fragmentation, prefill trace count,
 queue depth, TTFT/ITL percentiles, SLO-violation counts, prefill/decode
@@ -119,6 +123,13 @@ from repro.runtime.scheduler import Scheduler, SLOConfig
 # recover in place (faults_recovered); anything past it fails the ONE
 # request whose growth it was (request-scoped, never engine-fatal).
 _GROW_RETRIES = 3
+
+
+def serve_sample(logits: jax.Array) -> jax.Array:
+    """Greedy ids of a step's last-position logits: (N, 1, V) -> (N,), or
+    (N, 1, K, V) -> (N, K) for codebooks. int32; the first index wins a
+    tie, as in ``np.argmax``."""
+    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
 
 @dataclasses.dataclass
@@ -433,6 +444,11 @@ class Server:
         # bucket edge costs one retrace per DISTINCT bucket, not per flip.
         self._step_fn_cache: Dict[float, tuple] = {}
         self._build_step_fns()
+        # Greedy sampling depends on no plan or mesh, so one program
+        # (jit_serve_sample) serves every bucket: on a mesh the decode
+        # logits stay sharded over 'data', each shard takes the argmax of
+        # its own slots, and the ids gather in the host copy.
+        self._sample_ids = jax.jit(serve_sample)
         # Planner-v2 feedback loop: EMA of the realized block sparsity
         # (from the aux skip accounting). When the bucketed estimate
         # crosses a bucket edge, the MLP plans are rebuilt from the new
@@ -796,11 +812,16 @@ class Server:
             self.metrics.replans += 1
 
     # ------------------------------------------------------------ sampling
-    def _sample(self, logits: np.ndarray) -> np.ndarray:
-        """Vectorized sampling over (..., V): greedy or Gumbel-max."""
+    def _sample(self, logits: jax.Array) -> np.ndarray:
+        """Host token ids from a step's logits on the device, (N, 1, V)
+        or (N, 1, K, V): (N,) or (N, K). Greedy runs ``serve_sample``
+        and copies the ids; a temperature copies the logits and draws
+        Gumbel-max on the host."""
         if self.sc.temperature <= 0:
-            return np.argmax(logits, axis=-1)
-        z = logits.astype(np.float64) / self.sc.temperature
+            with device_program("sample"):
+                return np.asarray(self._sample_ids(logits))
+        last = np.asarray(logits, np.float32)[:, -1]
+        z = last.astype(np.float64) / self.sc.temperature
         u = self._rng.random(z.shape)
         g = -np.log(-np.log(np.clip(u, 1e-12, 1.0)))
         return np.argmax(z + g, axis=-1)
@@ -879,8 +900,9 @@ class Server:
                 else:
                     caches = model_lib.insert_slot_caches(
                         caches, small, slot)
-                # last_only logits: (1, 1, V) or (1, 1, K, V) for codes.
-                last = np.asarray(logits[0, 0], np.float32)  # (V,)/(K, V)
+                # The span holds the pool insert: the first token's
+                # sample, queued behind it on the device, waits for it.
+                jax.block_until_ready(caches)
                 skip = np.asarray(skip, np.float64)
             # Host-side trace ledger: one entry per (jitted fn, shape), so
             # it counts replan retraces too and stays a faithful fallback
@@ -891,7 +913,8 @@ class Server:
         self.metrics.prefill_tokens += S
         self.metrics.admitted += 1
         self._count_prefill_skip(skip)
-        return last, caches
+        # last_only logits, left on the device: (1, 1, V) or (1, 1, K, V).
+        return logits, caches
 
     def _count_prefill_skip(self, skip) -> None:
         """Fold a prefill's (skipped, total) tile-dot pair into both the
@@ -950,7 +973,7 @@ class Server:
                     caches, small, jnp.int32(slot), jnp.asarray(g_row),
                     jnp.int32(rows0), jnp.int32(prefix_len),
                 )
-                last = np.asarray(logits[0, 0], np.float32)  # (V,)/(K, V)
+                jax.block_until_ready(caches)
                 skip = np.asarray(skip, np.float64)
             self._prefill_shapes.add(
                 (id(self._prefill_cached), cfg.frontend, S_pad))
@@ -959,7 +982,7 @@ class Server:
         self.metrics.prefill_tokens += n_suffix
         self.metrics.admitted += 1
         self._count_prefill_skip(skip)
-        return last, caches
+        return logits, caches
 
     def _finish(self, slot_state: _Slot, t_now: float):
         r = slot_state.req
@@ -1702,7 +1725,7 @@ class Server:
                 prefix.register(
                     keys[:n_full],
                     [int(b) for b in st.tables[i, :n_full]])
-        first = self._sample(last_logits)  # () or (K,)
+        first = self._sample(last_logits)[0]  # () or (K,)
         # A restored request carries its pre-eviction tokens back in;
         # the prefill's last row re-ran the token whose decode tick the
         # preemption squashed, so ``first`` is exactly that tick's
@@ -1738,7 +1761,7 @@ class Server:
     def _decode_tick(self) -> int:
         """One fused decode tick for all slots (dead slots masked).
         Returns the number of live slots it decoded for (0 = no-op)."""
-        st, cfg, m = self._st, self.cfg, self.metrics
+        st, m = self._st, self.metrics
         with span(m, "decode_prep_s", "serve.decode.prep"):
             prep = self._prepare_tick()
         if prep is None:
@@ -1760,11 +1783,6 @@ class Server:
                     jax.block_until_ready((logits, st.caches, skip))
                 with span(m, "readback_s", "serve.decode.readback"):
                     skip = np.asarray(skip, np.float64)
-                    last = np.asarray(
-                        logits[:, -1] if cfg.frontend != "codes"
-                        else logits[:, 0],
-                        np.float32,
-                    )
         m.ticks += 1
         m.decode_tokens += n_active
         self._vt += 1.0
@@ -1775,7 +1793,9 @@ class Server:
         self._maybe_replan()
 
         with span(m, "sample_s", "serve.sample"):
-            nxt = self._sample(last)  # (B,) or (B, K)
+            nxt = self._sample(logits)  # (B,) or (B, K), on the host
+        if self.sc.temperature <= 0:
+            m.device_sample_ticks += 1
         with span(m, "emit_s", "serve.emit"):
             self._emit_tick(nxt)
         return n_active

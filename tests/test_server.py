@@ -3,6 +3,8 @@
 Engine-level tests run on the shared deterministic harness
 (tests/serving_harness.py): seeded traffic + cache-free greedy oracle.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,7 +24,6 @@ def _setup(arch, dropless_moe=False):
         # assignment that a 1-token decode keeps). The decode-consistency
         # invariant is exact only in the drop-free regime, so tests pin a
         # capacity factor that covers the worst-case load.
-        import dataclasses
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=16.0))
     params = model_lib.init_params(cfg, jax.random.PRNGKey(0))
@@ -292,6 +293,9 @@ def test_step_programs_have_stable_names():
         srv.params, {"tokens": jnp.zeros((1, 8), jnp.int32),
                      "advance": jnp.asarray([8], jnp.int32)},
     )) == "jit_serve_prefill"
+    assert module(srv._sample_ids.lower(
+        jnp.zeros((B, 1, srv.cfg.vocab_size), jnp.float32),
+    )) == "jit_serve_sample"
 
 
 def test_profiler_trace_holds_engine_spans(tmp_path):
@@ -317,3 +321,72 @@ def test_profiler_trace_holds_engine_spans(tmp_path):
             "serve.decode.readback", "serve.sample",
             "serve.emit"} <= names
     assert sum(n == "serve.prefill" for n, _, _ in host) == 2
+
+
+# ------------------------------------------------------------- sampling
+@pytest.fixture(scope="module")
+def greedy_server():
+    cfg, params = _setup("smollm-135m")
+    return Server(cfg, params, ServeConfig(batch_slots=2, max_len=32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", [(5, 1, 300), (3, 1, 4, 300), (1, 1, 300)],
+                         ids=["tick", "tick_codes", "admission"])
+def test_device_sample_matches_host_argmax(greedy_server, dtype, shape):
+    """Greedy ids chosen on the device equal np.argmax over the same
+    logits, the first index winning each tie: small integer values make
+    ties common, row 0 is all ties and row -1 ties at its ends."""
+    rng = np.random.default_rng(7)
+    x = rng.integers(-3, 4, shape).astype(np.float32)
+    x[0] = 1.0
+    x[-1, ..., 0] = x[-1, ..., -1] = 9.0
+    logits = jnp.asarray(x, dtype)
+    ids = greedy_server._sample(logits)
+    want = np.argmax(np.asarray(logits, np.float32)[:, -1], axis=-1)
+    assert ids.dtype == np.int32 and isinstance(ids, np.ndarray)
+    np.testing.assert_array_equal(ids, want)
+    assert (ids[0] == 0).all() and (ids[-1] == 0).all()
+
+
+class _HostSampling(Server):
+    """Sampling as it was before greedy moved to the device: the chosen
+    row(s) of logits copied to the host, then Gumbel-max in float64 on
+    the engine's seeded stream, (V,) at an admission, (B, V) a tick."""
+
+    def _sample(self, logits):
+        last = np.asarray(logits, np.float32)[:, -1]
+        admission = last.shape[0] != self.sc.batch_slots
+        if admission:
+            last = last[0]
+        z = last.astype(np.float64) / self.sc.temperature
+        u = self._rng.random(z.shape)
+        g = -np.log(-np.log(np.clip(u, 1e-12, 1.0)))
+        out = np.argmax(z + g, axis=-1)
+        return out[None] if admission else out
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8])
+def test_sampling_path_and_device_sample_ticks(temperature):
+    """Greedy ticks choose on the device and reproduce the cache-free
+    argmax oracle; a temperature keeps the host Gumbel-max draw,
+    token for token, and no tick counts as sampled on the device."""
+    cfg, params = _setup("smollm-135m")
+    reqs = make_traffic(cfg, Traffic(n_requests=5, prompt_lens=(3, 9),
+                                     max_new=(2, 7), seed=11))
+    sc = ServeConfig(batch_slots=3, max_len=32, temperature=temperature,
+                     seed=5)
+    if temperature <= 0:
+        _, m, _ = run_and_check(cfg, params, sc, reqs)
+        assert m.device_sample_ticks == m.ticks > 0
+        return
+    srv = Server(cfg, params, sc)
+    got = {r.uid: r.out for r in srv.generate(
+        [dataclasses.replace(r) for r in reqs])}
+    host = _HostSampling(cfg, params, sc)
+    want = {r.uid: r.out for r in host.generate(
+        [dataclasses.replace(r) for r in reqs])}
+    assert got.keys() == want.keys()
+    for uid in want:
+        np.testing.assert_array_equal(got[uid], want[uid])
+    assert srv.metrics.ticks > 0 and srv.metrics.device_sample_ticks == 0
