@@ -527,7 +527,14 @@ class Server:
         head/FFN/pool/slot counts, and -- when SparCE is on -- sparsity
         tile shapes that divide the PER-SHARD GEMM dims, so the psum'd
         tile-dot totals equal the single-host totals EXACTLY (ceil()
-        tile counts only distribute over even shards)."""
+        tile counts only distribute over even shards).
+
+        Measured deployment: Mistral-Nemo-12B whole on mesh (1, 4) of a
+        TPU v5e 2x2 host (the benchmark's ``mistral-nemo-12b-tp4.chat``):
+        per chip a quarter of every layer (5.45 GB) plus the replicated
+        embedding and head (2.68 GB), and a quarter of the KV heads of a
+        32-slot, 2,048-row pool (2.69 GB, twice while a decode step runs);
+        13.57 GB at peak on the fullest chip."""
         cfg, serve_cfg = self.cfg, self.sc
         dp, tp = serve_cfg.mesh
         if not self._paged:
@@ -1061,11 +1068,20 @@ class Server:
             # One sub-pool (with its own null block 0) and one allocator
             # per data shard; single-host is the one-shard case, where
             # this is byte-identical to the PR 3 layout.
-            caches = model_lib.init_paged_caches(
-                cfg, B, self._dp * (self._pool_shard + 1),
-                sc.kv_block_size)
-            if self._mesh is not None:
-                caches = jax.device_put(caches, self._pool_shardings())
+            n_blocks = self._dp * (self._pool_shard + 1)
+            if self._mesh is None:
+                caches = model_lib.init_paged_caches(
+                    cfg, B, n_blocks, sc.kv_block_size)
+            else:
+                # Made in place, shard by shard: the whole pool need not
+                # fit on one chip beside that chip's share of the weights.
+                def serve_init_pool():
+                    return model_lib.init_paged_caches(
+                        cfg, B, n_blocks, sc.kv_block_size)
+
+                caches = jax.jit(
+                    serve_init_pool,
+                    out_shardings=self._pool_shardings())()
             allocs = [
                 BlockAllocator(self._pool_shard)
                 for _ in range(self._dp)
