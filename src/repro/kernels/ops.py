@@ -235,12 +235,12 @@ def paged_decode_attn(
 
     Unlike the retired contiguous prototype (which hard-errored on
     ``L % block_l != 0``), the sequence dimension needs no tile
-    alignment at all: the kernel grids over the table width
-    (``max_blocks``, any positive int -- the pool is sized by
-    ``ceil(max_rows / block_size)``, never rounded up) and the index-map
-    clamp makes entries at/past each live length free, so there is
-    nothing to pad there. ``feat_align > 1`` additionally pads ragged
-    head dims up to that many lanes (zero features move no scores; the
+    alignment at all: the kernel walks each slot's live table prefix
+    (``max_blocks`` is any positive int -- the pool is sized by
+    ``ceil(max_rows / block_size)``, never rounded up) and copies no
+    entry at/past each live length, so there is nothing to pad there.
+    ``feat_align > 1`` additionally pads ragged head dims up to that
+    many lanes (zero features move no scores; the
     padded output columns are sliced off) -- an OPT-IN for compiled TPU
     mode with a non-lane-aligned head dim, because padding the pool
     here copies it every call; production pools should be ALLOCATED

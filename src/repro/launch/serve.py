@@ -45,12 +45,12 @@ the number of compiled prefill traces stays bounded under mixed traffic.
 Decode attention: --attn-kernel paged runs decode attention as a Pallas
 kernel straight out of the KV pool -- scalar-prefetched block tables +
 lengths (the SASA-entry analogue) let it never DMA dead slots' blocks,
-blocks past each live length, or null padding entries (index-map clamp =
-the PSRU's skip-before-fetch), instead of materializing the full
-(B, max_blocks x block_size) gather every tick. Token streams and skip
-statistics are identical to the default gather path (CI-gated); metrics
-gain the realized block-skip fraction and modeled attention HBM bytes
-saved.
+blocks past each live length, or null padding entries (each slot's live
+blocks are copied in chunks, the PSRU's skip-before-fetch), instead of
+materializing the full (B, max_blocks x block_size) gather every tick.
+Token streams and skip statistics are identical to the default gather
+path (CI-gated); metrics gain the realized block-skip fraction, modeled
+attention HBM bytes saved and the kernel's chunk steps.
 
 Prefix caching: --prefix-cache chain-hashes every prompt's full KV
 blocks into an index after prefill; later requests sharing a prefix map
@@ -347,6 +347,11 @@ def main(argv=None):
                   f"{(m.attn_bytes_gather - m.attn_bytes_paged)/1e6:.2f}"
                   f" MB modeled HBM bytes {realized} vs full-view "
                   "gather")
+        if m.attn_chunks:
+            fill = m.attn_blocks_fetched / (m.attn_chunks
+                                            * m.attn_chunk_blocks)
+            print(f"  decode attn chunks: {int(m.attn_chunks)} steps of "
+                  f"{int(m.attn_chunk_blocks)} blocks (fill {fill:.1%})")
     if m.prefix_cache_enabled:
         print(f"  prefix cache: {int(m.prefix_hits)}/"
               f"{int(m.prefix_lookups)} admissions hit "

@@ -323,7 +323,7 @@ def gqa_forward(
         if ck is None:
             # Fetch-skipping kernel straight out of the pool: the
             # scalar-prefetched (tables, lengths) pair is the SASA
-            # entry, the clamped index map the PSRU fetch elision.
+            # entry; only live blocks' pages are ever copied (PSRU).
             from repro.kernels import ops as kops
             o = kops.paged_decode_attn(
                 qd, new_cache.k, new_cache.v, block_tables,

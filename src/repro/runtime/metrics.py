@@ -100,6 +100,8 @@ class ServeMetrics:
     attn_kernel_paged: float = 0.0  # 1.0 when the Pallas kernel serves
     attn_blocks_fetched: float = 0.0
     attn_blocks_total: float = 0.0
+    attn_chunks: float = 0.0  # GQA kernel chunk steps, summed over ticks
+    attn_chunk_blocks: float = 0.0  # pool blocks a GQA kernel chunk holds
     attn_block_skip_fraction: float = 0.0
     attn_bytes_gather: float = 0.0
     attn_bytes_paged: float = 0.0

@@ -102,7 +102,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.core import cost_model, sasa
 from repro.parallel import sharding as shard_lib
-from repro.kernels.paged_decode_attn import decode_attn_block_counts
+from repro.kernels.paged_decode_attn import (
+    decode_attn_block_counts, decode_attn_chunks, gqa_chunk_blocks,
+)
 from repro.core.sparse_ops import SparsityConfig
 from repro.models import model as model_lib
 from repro.runtime.errors import (
@@ -517,6 +519,17 @@ class Server:
         self._occ_sum = 0.0
         self._attn_fetched = 0
         self._attn_total = 0
+        self._attn_chunks = 0
+        # Pool blocks one GQA kernel chunk holds, from the per-chip shapes
+        # the kernel sees (0: the GQA kernel serves no tick).
+        self._attn_chunk_blocks = 0
+        if (self._paged and serve_cfg.attn_kernel == "paged"
+                and cfg.mla is None):
+            self._attn_chunk_blocks = gqa_chunk_blocks(
+                self._max_blocks, serve_cfg.kv_block_size,
+                cfg.num_kv_heads // self._tp, cfg.resolved_head_dim,
+                jnp.dtype(cfg.dtype).itemsize)
+        self.metrics.attn_chunk_blocks = float(self._attn_chunk_blocks)
 
     def _init_mesh(self) -> None:
         """Validate and build the (data, model) serving mesh.
@@ -1909,6 +1922,9 @@ class Server:
                 eff, self._max_blocks, sc.kv_block_size)
             self._attn_fetched += fetched
             self._attn_total += total
+            if self._attn_chunk_blocks:
+                self._attn_chunks += decode_attn_chunks(
+                    eff, sc.kv_block_size, self._attn_chunk_blocks)
         self.metrics.kv_live_row_ticks += sum(
             s.cache_len for s in st.slots if s is not None)
         self.metrics.kv_pool_row_ticks += (
@@ -2123,6 +2139,7 @@ class Server:
         m = self.metrics
         m.attn_blocks_fetched = float(self._attn_fetched)
         m.attn_blocks_total = float(self._attn_total)
+        m.attn_chunks = float(self._attn_chunks)
         if not self._attn_total:
             return
         by = cost_model.decode_attn_hbm_bytes(

@@ -4,11 +4,13 @@ Three layers of contract, mirroring the paper's losslessness claim:
 
   * kernel vs oracle -- the Pallas kernel over the raw pool must match
     the gathered-view masked softmax (the gather path's dataflow) for
-    GQA and MLA, over ragged lengths, block edges and dead slots;
+    GQA and MLA, over ragged lengths, block edges, chunk edges and dead
+    slots (GQA also with NaN-filled fresh VMEM and a race detector);
   * fetch elision is REAL -- NaN-poisoned pool blocks outside every
     slot's live table prefix never reach the output (the PR 2 poisoned
-    technique), and the index-map clamp provably never ADDRESSES such a
-    block (enumerated host-side via ``clamped_block_ids``);
+    technique), and no page copy of the GQA kernel (host mirror
+    ``gqa_chunk_fetch_ids``) nor index-map step of the MLA kernel
+    (``clamped_block_ids``) ever ADDRESSES such a block;
   * engine parity -- ``ServeConfig.attn_kernel='paged'`` serves the
     seeded harness traffic token-identically to the gather oracle path,
     including SparCE skip statistics and the attention fetch telemetry.
@@ -24,8 +26,10 @@ from repro.configs import get_config
 from repro.core.sparse_ops import SparsityConfig
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.kernels import paged_decode_attn as pda
 from repro.kernels.paged_decode_attn import (
-    clamped_block_ids, decode_attn_block_counts, decode_attn_savings,
+    clamped_block_ids, decode_attn_block_counts, decode_attn_chunks,
+    decode_attn_savings, gqa_chunk_fetch_ids,
 )
 from repro.models import model as model_lib
 from repro.runtime.server import Request, ServeConfig, Server
@@ -74,6 +78,49 @@ def test_gqa_kernel_matches_gather_oracle(lengths):
     np.testing.assert_allclose(
         np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5)
     # Dead slots produce exactly zero (nothing fetched, nothing dotted).
+    assert np.all(np.asarray(got)[~live] == 0.0)
+
+
+CHUNK_P, CHUNK_MAX_BLOCKS = 3, 8  # a table width that P does not divide
+
+
+@pytest.fixture
+def chunk_p3(monkeypatch):
+    """The GQA kernel at P = 3 pool blocks a chunk (the rows it aims for
+    cut to 3 blocks), traced afresh on entry and on exit."""
+    monkeypatch.setattr(pda, "_CHUNK_ROWS", CHUNK_P * BS)
+    pda.paged_gqa_decode_attn.clear_cache()
+    assert pda.gqa_chunk_blocks(CHUNK_MAX_BLOCKS, BS, 2, 16, 4) == CHUNK_P
+    yield
+    pda.paged_gqa_decode_attn.clear_cache()
+
+
+@pytest.mark.parametrize("edge", [
+    1, BS, CHUNK_P * BS - 1, CHUNK_P * BS, CHUNK_P * BS + 1,
+    CHUNK_MAX_BLOCKS * BS, 0])
+def test_gqa_kernel_chunk_edges_nan_memory_no_races(chunk_p3, edge):
+    """Lengths on every chunk edge, a partial last chunk, a dead slot
+    between live ones (it only prefetches its neighbour's first chunk),
+    under an interpreter whose fresh VMEM is NaN and which watches the
+    double buffer for races: stale rows of a partial chunk, or a chunk
+    read before its copy lands, would show as NaN or as a race."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    rng = np.random.default_rng(edge)
+    q, kp, vp, tables, ln = _gqa_case(
+        rng, [edge, CHUNK_P * BS + 2, 0, edge],
+        max_blocks=CHUNK_MAX_BLOCKS)
+    got = pda.paged_gqa_decode_attn(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(ln),
+        interpret=pltpu.InterpretParams(
+            uninitialized_memory="nan", detect_races=True))
+    assert not interpret_pallas_call.races.races_found
+    want = kref.paged_gqa_decode_attn_ref(q, kp, vp, jnp.asarray(tables),
+                                          jnp.asarray(ln))
+    live = ln > 0
+    np.testing.assert_allclose(
+        np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5)
     assert np.all(np.asarray(got)[~live] == 0.0)
 
 
@@ -140,7 +187,9 @@ def test_nan_poisoned_dead_blocks_never_reach_output():
     q, kp, vp, tables, ln = _gqa_case(rng, [9, 0, 24, 3])
     base = kops.paged_decode_attn(q, kp, vp, jnp.asarray(tables),
                                   jnp.asarray(ln))
-    live_ids = set(clamped_block_ids(tables, ln, BS).ravel().tolist())
+    P = pda.gqa_chunk_blocks(tables.shape[1], BS, 2, 16, 4)
+    live_ids = {i for chunks in gqa_chunk_fetch_ids(tables, ln, BS, P)
+                for chunk in chunks for i in chunk}
     dead = np.array([i for i in range(kp.shape[0]) if i not in live_ids])
     assert dead.size > 0
     kp2 = np.asarray(kp).copy()
@@ -174,8 +223,8 @@ def test_nan_poison_past_live_length_in_same_block():
 
 
 def test_index_map_clamp_never_addresses_dead_blocks():
-    """The fetch-elision contract in closed form: for EVERY grid step
-    the clamped index map resolves to a block inside the slot's live
+    """The MLA kernel's fetch-elision contract in closed form: for EVERY
+    grid step the clamped index map resolves to a block inside the slot's live
     table prefix (or the slot's entry 0 when dead) -- a dead/null/
     past-length table entry is never even addressed, which is what
     distinguishes fetch elision from post-fetch masking."""
@@ -197,11 +246,38 @@ def test_index_map_clamp_never_addresses_dead_blocks():
         assert poison not in set(ids[b].tolist()) or lengths[b] == 0
 
 
+@pytest.mark.parametrize("P", [1, 3, 8])
+def test_gqa_chunk_copies_never_address_dead_blocks(P):
+    """The GQA kernel's page copies in closed form: every chunk copy
+    names a table entry inside the slot's live prefix, in table order,
+    each live entry exactly once, ``P`` to a chunk with only the last
+    one partial; a dead slot names nothing. Entries past the prefix
+    point at a poison id that no copy may name."""
+    rng = np.random.default_rng(P)
+    B, max_blocks = 6, 10
+    lengths = np.array([0, 1, BS, P * BS + 1, 3 * BS - 1,
+                        max_blocks * BS], np.int32)
+    tables = _rand_tables(rng, B, max_blocks, lengths, B * max_blocks + 1)
+    poison = 10_000
+    for b in range(B):
+        tables[b, -(-int(lengths[b]) // BS):] = poison
+    copies = gqa_chunk_fetch_ids(tables, lengths, BS, P)
+    for b in range(B):
+        live = -(-int(lengths[b]) // BS)
+        chunks = copies[b]
+        assert [i for c in chunks for i in c] == tables[b, :live].tolist()
+        assert all(len(c) == P for c in chunks[:-1])
+        assert len(chunks) == -(-live // P)
+    assert copies[0] == []
+    assert decode_attn_chunks(lengths, BS, P) == sum(map(len, copies))
+
+
 def test_block_savings_accounting():
     fetched, total = decode_attn_block_counts([0, 1, 8, 9], 6, BS)
     assert (fetched, total) == (0 + 1 + 2 + 3, 4 * 6)
     assert decode_attn_savings([0, 1, 8, 9], 6, BS) == 1.0 - 6 / 24
     assert decode_attn_savings([], 6, BS) == 0.0
+    assert decode_attn_chunks([0, 1, 8, 9], BS, 2) == 0 + 1 + 1 + 2
 
 
 # ---------------------------------------------------------- engine parity
@@ -249,6 +325,12 @@ def test_engine_gqa_paged_kernel_token_identical_with_skip_stats():
     # no saving; the block accounting itself is schedule-identical.
     assert m_g["modeled_attn_bytes_saved"] == 0.0
     assert m_g["attn_blocks_fetched"] == m_p["attn_blocks_fetched"]
+    # One chunk covers a whole table here (P = max_blocks), so the GQA
+    # kernel runs one chunk step per live slot a tick; the gather path
+    # runs none.
+    assert m_p["attn_chunk_blocks"] == 64 // 8
+    assert m_p["attn_chunks"] == m_p["decode_tokens"]
+    assert m_g["attn_chunks"] == 0.0
 
 
 def test_engine_mla_paged_kernel_token_identical():

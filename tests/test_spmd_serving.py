@@ -26,7 +26,9 @@ from repro.configs import get_config
 from repro.core.sparse_ops import SparsityConfig
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
-from repro.kernels.paged_decode_attn import clamped_block_ids
+from repro.kernels.paged_decode_attn import (
+    gqa_chunk_blocks, gqa_chunk_fetch_ids,
+)
 from repro.parallel import sharding as shard_lib
 from repro.runtime.server import ServeConfig
 from serving_harness import Traffic, make_traffic, run_server
@@ -254,12 +256,15 @@ def test_sharded_paged_attn_nan_poisoned_blocks_never_fetched(cpu_mesh):
     live = lengths > 0
     np.testing.assert_allclose(base[live], np.asarray(want)[live],
                                rtol=2e-5, atol=2e-5)
-    # Poison every block no shard's clamped index map ever addresses.
+    # Poison every block no shard's page copies ever name.
+    chunk = gqa_chunk_blocks(tables.shape[1], BS, kp.shape[2] // tp,
+                             kp.shape[3], kp.dtype.itemsize)
     live_ids = set()
     for sh in range(dp):
         rows = slice(sh * (B // dp), (sh + 1) * (B // dp))
-        ids = clamped_block_ids(tables[rows], lengths[rows], BS)
-        live_ids |= {int(i) + sh * shard_nb for i in ids.ravel()}
+        for chunks in gqa_chunk_fetch_ids(tables[rows], lengths[rows], BS,
+                                          chunk):
+            live_ids |= {i + sh * shard_nb for c in chunks for i in c}
     dead = np.array([i for i in range(kp.shape[0]) if i not in live_ids])
     assert dead.size > 0
     kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()

@@ -91,10 +91,18 @@ def test_relu_bitmap_compiles(compile_for_tpu, dtype):
     assert "tpu_custom_call" in hlo
 
 
-def test_paged_gqa_decode_compiles(compile_for_tpu):
-    kv, hd = SMOLLM.num_kv_heads, SMOLLM.resolved_head_dim
-    g = SMOLLM.num_heads // kv
-    B, bs, max_blocks = 8, 16, 16
+@pytest.mark.parametrize("kv,g,hd,B,max_blocks", [
+    # per-chip shapes of the benchmark's cells, at their B and table width
+    (SMOLLM.num_kv_heads, SMOLLM.num_heads // SMOLLM.num_kv_heads,
+     SMOLLM.resolved_head_dim, 128, 128),  # smollm-135m: max_len 2048
+    (NEMO.num_kv_heads, NEMO.num_heads // NEMO.num_kv_heads,
+     NEMO.resolved_head_dim, 64, 96),  # mistral-nemo-12b-s8: 1536
+    (NEMO.num_kv_heads // 4, NEMO.num_heads // NEMO.num_kv_heads,
+     NEMO.resolved_head_dim, 32, 128),  # mistral-nemo-12b-tp4, one chip
+], ids=["smollm-135m", "mistral-nemo-12b-s8", "mistral-nemo-12b-tp4"])
+def test_paged_gqa_decode_compiles(compile_for_tpu, kv, g, hd, B,
+                                   max_blocks):
+    bs = 16
     nb = B * max_blocks + 1
     hlo = compile_for_tpu(
         lambda q, k, v, t, n: kops.paged_decode_attn(
